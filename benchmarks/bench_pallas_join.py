@@ -1,6 +1,6 @@
 """Accelerator-resident relational pipeline: the star-schema join +
 group-by of ``bench_join`` with the kernel hot path running through the
-Pallas ports (``ZERROW_KERNEL_BACKEND=pallas``) instead of numpy.
+device kernels (``ZERROW_KERNEL_BACKEND=pallas``) instead of numpy.
 
     load orders (fact: cust id + amount cents)  ─┐
                                                  ├─> join (left, cust)
@@ -12,20 +12,20 @@ Pallas ports (``ZERROW_KERNEL_BACKEND=pallas``) instead of numpy.
 The fact payload is integer cents, so every aggregate sits on the
 *eligible* side of the kernel registry — the whole join+group_by cone
 (splitmix64 key hashing, sentinel join gathers, integer segment
-reducers) runs accelerator-resident, interpret-mode on CI runners and
-compiled on a real TPU, and must land on **exactly the numpy bits**.
+reducers) runs on jax's default device — the CPU on CI runners, the
+TPU on the chip — and must land on **exactly the numpy bits**.
 
 Both arms run the same DAGs on the same thread-mode executor; the
 backend env var is the only difference.  Recorded per arm: wall-clock
-and the pallas/numpy wall ratio (interpret mode is a *semantics* lane,
-not a speed lane — on CPU runners the ratio is expected >> 1; the
-number that matters there is the bit-identity, the ratio matters once a
-TPU runs compiled).  Always gated, in smoke too:
+and the pallas/numpy wall ratio (on CPU runners this is a *semantics*
+lane, not a speed lane: the number that matters there is the
+bit-identity; ``chip_smoke.py`` drives the same path on the chip).
+Always gated, in smoke too:
 
   * aggregate outputs bit-identical across backends (to_pydict AND raw
     primitive buffers: same dtypes, same bits, NaN-aware);
-  * ``kdispatch.self_check()`` demotes nothing — every admitted kernel
-    still reproduces the numpy reference exactly;
+  * ``kdispatch.self_check()`` passes (it raises on any admitted
+    kernel that no longer reproduces the numpy reference exactly);
   * the registry still documents its ineligible float entries (the PR 5
     sequential-float-sum contract must never silently flip to parallel).
 
@@ -113,8 +113,7 @@ def _run_arm(backend: str, paths, est, reps: int):
     """Best-of-``reps`` runs of fresh DAGs on one warm thread-mode env
     with the given kernel backend; returns (wall, pydicts, raw bits)."""
     os.environ[_BACKEND_ENV] = backend
-    assert kdispatch.active_backend() == backend, \
-        f"backend {backend} unavailable: {kdispatch.pallas_import_error()!r}"
+    assert kdispatch.active_backend() == backend
     env = make_env(workers=1, workers_mode="thread", decache=False)
     best = None
     try:
@@ -151,16 +150,12 @@ def _assert_bit_identical(numpy_arm, pallas_arm):
 
 
 def main() -> None:
-    from repro.kernels import ops as kops   # deferred: needs jax
+    from repro.kernels import relational   # deferred: needs jax
     os.environ[_BACKEND_ENV] = "pallas"
     try:
-        # admission gate first: a kernel whose differential fails is
-        # demoted and FAILS the bench — the registry must reject it
-        # before it can serve a single query
+        # admission gate first: a kernel whose differential fails raises
+        # here and FAILS the bench before it can serve a single query
         report = kdispatch.self_check()
-        demoted = {k: v for k, v in report.items()
-                   if v.startswith("demoted")}
-        assert not demoted, f"kernels lost bit-identity: {demoted}"
         ineligible = [k for k, v in report.items()
                       if v.startswith("ineligible")]
         assert "grouped_sum:float" in ineligible, \
@@ -173,7 +168,7 @@ def main() -> None:
     est = int(tables[0][0].nbytes * 4)
     results = {"n_dags": N_DAGS, "smoke": SMOKE,
                "orders_bytes": sum(o.nbytes for o, _ in tables),
-               "interpret": kops.default_interpret(),
+               "platform": relational.platform(),
                "self_check_ok": sorted(k for k, v in report.items()
                                        if v == "ok"),
                "self_check_ineligible": sorted(ineligible),
@@ -197,12 +192,12 @@ def main() -> None:
                             "reps": reps})
     results["runs"].append({"backend": "pallas", "wall_s": t_pl,
                             "reps": reps,
-                            "interpret": results["interpret"]})
+                            "platform": results["platform"]})
     results["pallas_over_numpy"] = t_pl / t_np
     Csv.add("pallas_join_numpy", t_np, "bit_identity=pass")
     Csv.add("pallas_join_pallas", t_pl,
             f"{t_pl / t_np:.2f}x_of_numpy;"
-            f"interpret={int(results['interpret'])};"
+            f"platform={results['platform']};"
             f"self_check={len(results['self_check_ok'])}ok")
     if SMOKE:
         print("# smoke: pallas arm bit-identical to numpy pipeline, "
@@ -215,8 +210,8 @@ def main() -> None:
     with open(out, "w") as fh:
         json.dump(results, fh, indent=2)
     print(f"# wrote {out}: numpy {t_np:.2f}s, pallas "
-          f"{t_pl:.2f}s ({t_pl / t_np:.2f}x, interpret="
-          f"{int(results['interpret'])})")
+          f"{t_pl:.2f}s ({t_pl / t_np:.2f}x, platform="
+          f"{results['platform']})")
 
 
 if __name__ == "__main__":

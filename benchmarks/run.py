@@ -2,10 +2,11 @@
 
     PYTHONPATH=src python -m benchmarks.run [--smoke] [figure ...]
 
-Prints ``name,us_per_call,derived`` CSV rows.  ``--smoke`` runs a quick
-CI-sized subset at a heavily reduced scale (so the bench scripts cannot
-rot without the build noticing); it must be passed before importing any
-benchmark module because the scale is read at import time.
+Prints ``name,us_per_call,derived`` CSV rows and exits nonzero if any
+selected bench failed.  ``--smoke`` runs a quick CI-sized subset at a
+heavily reduced scale (so the bench scripts cannot rot without the build
+noticing); it must be passed before importing any benchmark module
+because the scale is read at import time.
 """
 
 import os
@@ -25,9 +26,9 @@ import sys
 #: asserts the overload/fault story — typed shed outcomes, a balanced
 #: admission ledger, bounded fault-arm p99, and zero wrong results
 #: while workers are being killed mid-request; 'pallas_join' asserts
-#: the accelerator kernel backend (interpret-mode Pallas) produces
-#: bit-identical join+group_by aggregates to the numpy pipeline and
-#: that the kdispatch self-check demotes nothing.
+#: the device kernel backend produces bit-identical join+group_by
+#: aggregates to the numpy pipeline and that the kdispatch self-check
+#: passes.
 SMOKE_FIGURES = ("fig2", "fig6", "concurrency", "flight", "diffcache",
                  "kernels", "join", "query", "ingest", "serve_load",
                  "pallas_join")
@@ -40,6 +41,8 @@ def main() -> None:
         args = [a for a in args if a != "--smoke"]
         os.environ.setdefault("ZERROW_BENCH_SCALE", "256")
         os.environ["ZERROW_BENCH_SMOKE"] = "1"
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_concurrency, bench_diffcache, bench_flight,
                    bench_ingest, bench_join, bench_kernels,
                    bench_pallas_join, bench_query,
@@ -80,8 +83,8 @@ def main() -> None:
         except Exception as e:  # keep the harness going
             failed.append(name)
             print(f"{name},0.0,ERROR:{e!r}")
-    if smoke and failed:
-        raise SystemExit(f"smoke benchmarks failed: {failed}")
+    if failed:
+        raise SystemExit(f"benchmarks failed: {failed}")
 
 
 if __name__ == "__main__":

@@ -1,89 +1,47 @@
-"""Version-compatibility shims over the moving parts of the jax API.
+"""The jax API the repo uses, in the spelling of the installed jax (0.9).
 
-The repo targets the jax baked into the container; upstream has moved
-three symbols we rely on between releases:
+Import these symbols from here instead of from jax directly, so that the
+next upstream move is repaired in this one file:
 
-  * ``shard_map``   — lived in ``jax.experimental.shard_map`` until it was
-                      promoted to ``jax.shard_map``;
-  * ``AxisType``    — ``jax.make_mesh(..., axis_types=...)`` only exists on
-                      newer jax; older versions take no ``axis_types`` and
-                      treat every axis as auto;
-  * ``optimization_barrier`` — older jax ships the primitive without a
-                      differentiation rule, so any model that barriers its
-                      gathered params inside a scanned/checkpointed block
-                      fails under ``jax.grad`` with NotImplementedError.
-
-Import from here instead of from jax directly.
+  * ``shard_map``   — ``jax.shard_map`` (``check_vma``, ``axis_names``);
+  * ``make_mesh``   — ``jax.make_mesh`` with every axis ``AxisType.Auto``;
+  * ``cost_analysis`` — ``Compiled.cost_analysis()`` as a flat dict;
+  * ``enable_x64``  — scoped 64-bit mode (``jax.enable_x64``; the old
+                      ``jax.experimental.enable_x64`` is gone);
+  * ``optimization_barrier`` — ``jax.lax.optimization_barrier`` with a
+                      VJP that barriers the cotangents too.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-# -- shard_map ---------------------------------------------------------------
-try:                                       # promoted top-level location
-    _shard_map_impl = jax.shard_map
-except AttributeError:                     # supported pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
+shard_map = jax.shard_map
 
 
-def shard_map(f, **kwargs):
-    """``shard_map`` accepting the newest keyword spelling on any version.
-
-    Newer jax renamed ``check_rep`` -> ``check_vma`` and replaced the
-    ``auto`` axis set with its complement ``axis_names`` (the axes that are
-    *manual*); translate to whatever the installed version understands.
-    """
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    if "axis_names" in kwargs and "axis_names" not in _SM_PARAMS:
-        manual = set(kwargs.pop("axis_names"))
-        mesh = kwargs.get("mesh")
-        if mesh is not None and "auto" in _SM_PARAMS:
-            auto = frozenset(mesh.axis_names) - manual
-            if auto:
-                kwargs["auto"] = auto
-    return _shard_map_impl(f, **kwargs)
-
-
-# -- make_mesh with axis_types ----------------------------------------------
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    Newer jax distinguishes Auto/Explicit axis types; older jax has no
-    ``AxisType`` and every axis is implicitly auto, so dropping the
-    argument is semantically identical there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axis_names)
-    return jax.make_mesh(shape, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
-# -- compiled-artifact cost analysis ----------------------------------------
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every version.
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return dict(compiled.cost_analysis())
 
-    Older jax returns a list with one per-computation dict; newer jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+
+def enable_x64():
+    """Context manager scoping 64-bit mode to a block: arrays made and
+    programs traced inside it keep uint64/int64 lanes, while the
+    default-dtype behaviour of the model code outside it is untouched."""
+    return jax.enable_x64(True)
 
 
 # -- differentiable optimization_barrier -------------------------------------
 @jax.custom_vjp
 def optimization_barrier(x):
-    """``jax.lax.optimization_barrier`` that is differentiable on every
-    supported jax version.
+    """``jax.lax.optimization_barrier`` with an explicit VJP.
 
     The barrier is semantically the identity, so its VJP is the identity
     on cotangents; we barrier the cotangents too, matching the scheduling

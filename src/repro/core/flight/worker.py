@@ -49,6 +49,7 @@ thread executor only approximated for GIL-releasing decompression).
 from __future__ import annotations
 
 import collections
+import contextlib
 import multiprocessing as mp
 import os
 import pickle
@@ -66,6 +67,29 @@ from .wire import (WireError, decode_message, encode_message, frame_refs,
                    recv_frame, send_frame)
 
 _SPAWN = mp.get_context("spawn")      # never fork: jax/threads unsafe
+
+#: environment every worker is spawned with: workers compute on the host
+#: only — a chip belongs to one process, and the parent may hold it
+_WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
+_spawn_env_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _worker_env():
+    """Hold ``_WORKER_ENV`` in ``os.environ`` while workers are spawned
+    (a spawned child inherits the environment of the moment it starts),
+    then restore the parent's values."""
+    with _spawn_env_lock:
+        saved = {k: os.environ.get(k) for k in _WORKER_ENV}
+        os.environ.update(_WORKER_ENV)
+        try:
+            yield
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
 
 # Worker-side fault points (fired in the worker process, armed through the
 # inherited environment — ZERROW_FAULTS crosses the spawn boundary):
@@ -554,16 +578,17 @@ class FlightWorkerPool:
         spawned procs and raises; otherwise (crash-recovery respawn) the
         stragglers are terminated and the paired survivors returned."""
         procs = []
-        for _ in range(n):
-            i = self._next_worker
-            self._next_worker += 1
-            p = _SPAWN.Process(
-                target=worker_main,
-                args=(self._sock_path,
-                      os.path.join(self.data_root, f"w{i}")),
-                name=f"zerrow-flight-{i}", daemon=True)
-            p.start()
-            procs.append(p)
+        with _worker_env():
+            for _ in range(n):
+                i = self._next_worker
+                self._next_worker += 1
+                p = _SPAWN.Process(
+                    target=worker_main,
+                    args=(self._sock_path,
+                          os.path.join(self.data_root, f"w{i}")),
+                    name=f"zerrow-flight-{i}", daemon=True)
+                p.start()
+                procs.append(p)
         by_pid = {p.pid: p for p in procs}
         new: List[WorkerHandle] = []
         try:

@@ -1,13 +1,14 @@
-"""Kernel backend dispatch: numpy vkernels vs Pallas relational kernels.
+"""Kernel backend dispatch: numpy vkernels vs device relational kernels.
 
 ``ZERROW_KERNEL_BACKEND={numpy,pallas}`` selects where the relational
 hot path (key hashing, join gathers, segment reducers) runs.  The
 default is ``numpy`` (``core/vkernels.py``); ``pallas`` routes each
-kernel call through ``repro.kernels.relational`` — accelerator-resident
-on TPU, interpret-mode on CI runners — **but only for kernels the
-eligibility registry admits**.  Admission is a bit-identity contract:
-a kernel enters ``REGISTRY`` as eligible only once the differential
-harness (``tests/test_pallas_relational.py``) proves pallas-interpret
+kernel call through ``repro.kernels.relational`` — jitted programs on
+jax's default device (the TPU on the chip, the CPU under
+``JAX_PLATFORMS=cpu``) — **but only for kernels the eligibility
+registry admits**.  Admission is a bit-identity contract: a kernel
+enters ``REGISTRY`` as eligible only once the differential harness
+(``tests/test_pallas_relational.py``) proves device-kernel
 bits equal the numpy reference bits equal a per-row naive reference,
 across dtypes, nulls, duplicates, and empties.  Kernels that *cannot*
 meet the contract are documented ineligible with their reason and stay
@@ -18,30 +19,31 @@ order (``np.bincount`` original-row-order for sums; left-to-right
 reduction cannot reproduce bit-for-bit.  Position-dependent float
 accumulation must never silently change results.
 
-Fallback is loud-but-safe: requesting ``pallas`` without a working
-jax/Pallas install warns once (naming this knob and the import error)
-and serves numpy; an unknown backend name raises.  ``self_check()``
-re-runs a compact differential in-process and *demotes* any kernel
-whose bits diverge (runtime numpy fallback + reported) — the
-``bench_pallas_join`` smoke gate asserts no demotions, so an eligibility
-regression fails CI.
+There is no hidden fallback.  Requesting ``pallas`` where the device
+kernels cannot be imported raises, naming this knob and the cause; an
+unknown backend name raises; ``self_check()`` re-runs a compact
+differential in-process and raises on any kernel whose bits diverge.
+Every call the device kernels serve is counted per kernel and device
+platform (``device_calls()``), which is how a run proves its hot path
+ran on the chip.
 
 Fingerprint integration: ``fp_includes_join`` / ``fp_includes_group_by``
 are *callables* assigned to the relational ops' ``__fp_includes__``
 (``core/fingerprint.py`` calls them at fingerprint time).  They always
 return the numpy kernel deps; when the pallas backend is active they
-additionally fold in a backend tag plus the live Pallas kernel
-functions.  Flipping ``ZERROW_KERNEL_BACKEND``, editing a Pallas kernel
-body, or a runtime demotion therefore invalidates exactly the cached
-join/group-by cones — switching backends can never serve a stale cone
-computed by the other engine.
+additionally fold in a backend tag plus the live device kernel
+functions.  Flipping ``ZERROW_KERNEL_BACKEND`` or editing a device
+kernel therefore invalidates exactly the cached join/group-by cones —
+switching backends can never serve a stale cone computed by the other
+engine.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
-import warnings
+import threading
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -51,8 +53,8 @@ from . import vkernels
 
 __all__ = [
     "BACKENDS", "REGISTRY", "Eligibility", "requested_backend",
-    "active_backend", "pallas_import_error", "eligible", "demoted",
-    "demote", "reset_demotions", "self_check",
+    "active_backend", "eligible", "self_check", "device_calls",
+    "reset_device_calls",
     "hash_fixed", "combine_hashes", "hash_keys", "filter_join_gather",
     "GROUPED_REDUCERS", "fp_includes_join", "fp_includes_group_by",
 ]
@@ -79,12 +81,11 @@ def requested_backend() -> str:
 
 _pallas_mod = None
 _pallas_error: Optional[BaseException] = None
-_warned_fallback = False
 
 
 def _pallas():
     """Import ``repro.kernels.relational`` lazily, once; the failure (if
-    any) is kept for the loud fallback message."""
+    any) is kept for the error ``active_backend`` raises."""
     global _pallas_mod, _pallas_error
     if _pallas_mod is None and _pallas_error is None:
         try:
@@ -95,28 +96,46 @@ def _pallas():
     return _pallas_mod
 
 
-def pallas_import_error() -> Optional[BaseException]:
-    """The exception that made the pallas backend unavailable, or None."""
-    _pallas()
-    return _pallas_error
-
-
 def active_backend() -> str:
-    """The backend actually serving calls: the requested one, demoted to
-    numpy — with a one-time warning naming the knob and the import
-    failure — when pallas was requested but jax/Pallas cannot load."""
-    global _warned_fallback
+    """The backend serving calls: the requested one.  Requesting
+    ``pallas`` where the device kernels cannot be imported raises,
+    naming the knob and the cause — never a silent numpy fallback."""
     b = requested_backend()
     if b == "pallas" and _pallas() is None:
-        if not _warned_fallback:
-            warnings.warn(
-                f"{_ENV}=pallas requested but the Pallas kernels are "
-                f"unavailable ({_pallas_error!r}); falling back to the "
-                "numpy vkernels. Results are identical; unset "
-                f"{_ENV} to silence this.", RuntimeWarning, stacklevel=2)
-            _warned_fallback = True
-        return "numpy"
+        raise RuntimeError(
+            f"{_ENV}=pallas, but the device kernels "
+            f"(repro.kernels.relational) failed to import: "
+            f"{_pallas_error!r}. Repair the jax install, or set "
+            f"{_ENV}=numpy to run on the numpy vkernels."
+        ) from _pallas_error
     return b
+
+
+#: calls served by the device kernels, keyed (kernel, device platform)
+_device_calls: collections.Counter = collections.Counter()
+_device_calls_lock = threading.Lock()
+
+
+def device_calls() -> Dict[Tuple[str, str], int]:
+    """Calls the device kernels served since the last reset, keyed
+    ``(kernel, platform)`` — ``("hash_fixed", "tpu")`` counts hashes
+    that ran on the chip."""
+    with _device_calls_lock:
+        return dict(_device_calls)
+
+
+def reset_device_calls() -> None:
+    with _device_calls_lock:
+        _device_calls.clear()
+
+
+def _on_device(kernel: str, *args):
+    """Serve one call from the device kernel ``kernel`` and count it."""
+    out = getattr(_pallas_mod, kernel)(*args)
+    key = (kernel, _pallas_mod.platform())
+    with _device_calls_lock:
+        _device_calls[key] += 1
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -171,11 +190,6 @@ REGISTRY: Dict[str, Eligibility] = {
         "accumulation contract"),
 }
 
-#: registry keys demoted at runtime by ``self_check`` bit mismatches;
-#: demoted kernels serve numpy and fail the bench smoke gate
-_demoted: Dict[str, str] = {}
-
-
 def _dtype_class(dt) -> str:
     dt = np.dtype(dt)
     return "float" if np.issubdtype(dt, np.floating) else "int"
@@ -189,25 +203,10 @@ def _registry_key(kernel: str, dtype=None) -> str:
 
 def eligible(kernel: str, dtype=None) -> bool:
     """Is this kernel (for this value dtype, if reductions) admitted to
-    the pallas path right now?  False for documented-ineligible entries,
-    unknown kernels, and runtime demotions alike."""
-    key = _registry_key(kernel, dtype)
-    e = REGISTRY.get(key)
-    return bool(e and e.eligible) and key not in _demoted
-
-
-def demoted() -> Dict[str, str]:
-    """Registry keys demoted at runtime, with the mismatch description."""
-    return dict(_demoted)
-
-
-def demote(kernel_key: str, why: str) -> None:
-    """Force a kernel onto the numpy path (bit mismatch observed)."""
-    _demoted[kernel_key] = why
-
-
-def reset_demotions() -> None:
-    _demoted.clear()
+    the device path?  False for documented-ineligible entries and
+    unknown kernels alike."""
+    e = REGISTRY.get(_registry_key(kernel, dtype))
+    return bool(e and e.eligible)
 
 
 # --------------------------------------------------------------------------
@@ -220,13 +219,13 @@ def _use_pallas(kernel: str, dtype=None) -> bool:
 
 def hash_fixed(values: np.ndarray) -> np.ndarray:
     if _use_pallas("hash_fixed", values.dtype):
-        return _pallas_mod.hash_fixed(values)
+        return _on_device("hash_fixed", values)
     return vkernels.hash_fixed(values)
 
 
 def combine_hashes(col_hashes: Sequence[np.ndarray], n: int) -> np.ndarray:
     if _use_pallas("combine_hashes"):
-        return _pallas_mod.combine_hashes(col_hashes, n)
+        return _on_device("combine_hashes", col_hashes, n)
     return vkernels.combine_hashes(col_hashes, n)
 
 
@@ -234,13 +233,13 @@ def hash_keys(keys: Sequence, n: int) -> np.ndarray:
     # var-length (offsets, values) keys are structurally numpy-only
     if (_use_pallas("hash_keys")
             and not any(isinstance(k, tuple) for k in keys)):
-        return _pallas_mod.hash_keys(keys, n)
+        return _on_device("hash_keys", keys, n)
     return vkernels.hash_keys(list(keys), n)
 
 
 def filter_join_gather(sel: np.ndarray, idx: np.ndarray) -> np.ndarray:
     if _use_pallas("filter_join_gather"):
-        return _pallas_mod.filter_join_gather(sel, idx)
+        return _on_device("filter_join_gather", sel, idx)
     return vkernels.filter_join_gather(sel, idx)
 
 
@@ -248,7 +247,7 @@ def gather_payload(values: np.ndarray, idx: np.ndarray,
                    fill=0) -> np.ndarray:
     idx = np.ascontiguousarray(idx, dtype=np.int64)
     if _use_pallas("gather_payload", values.dtype):
-        return _pallas_mod.gather_payload(values, idx, fill)
+        return _on_device("gather_payload", values, idx, fill)
     out = np.full(len(idx), fill, dtype=values.dtype)
     hit = idx >= 0
     out[hit] = values[idx[hit]]
@@ -257,25 +256,25 @@ def gather_payload(values: np.ndarray, idx: np.ndarray,
 
 def _r_count(values, order, starts, valid=None):
     if _use_pallas("grouped_count"):
-        return _pallas_mod.grouped_count(values, order, starts, valid)
+        return _on_device("grouped_count", values, order, starts, valid)
     return vkernels.grouped_count(values, order, starts, valid)
 
 
 def _r_sum(values, order, starts, valid=None):
     if _use_pallas("grouped_sum", values.dtype):
-        return _pallas_mod.grouped_sum(values, order, starts, valid)
+        return _on_device("grouped_sum", values, order, starts, valid)
     return vkernels.grouped_sum(values, order, starts, valid)
 
 
 def _r_min(values, order, starts, valid=None):
     if _use_pallas("grouped_min", values.dtype):
-        return _pallas_mod.grouped_min(values, order, starts, valid)
+        return _on_device("grouped_min", values, order, starts, valid)
     return vkernels.grouped_min(values, order, starts, valid)
 
 
 def _r_max(values, order, starts, valid=None):
     if _use_pallas("grouped_max", values.dtype):
-        return _pallas_mod.grouped_max(values, order, starts, valid)
+        return _on_device("grouped_max", values, order, starts, valid)
     return vkernels.grouped_max(values, order, starts, valid)
 
 
@@ -295,16 +294,14 @@ GROUPED_REDUCERS = {
 # fingerprint integration: callable __fp_includes__ for the relational ops
 # --------------------------------------------------------------------------
 
-def _backend_tag(name: str, demotions: Tuple[str, ...]) -> None:
+def _backend_tag(name: str) -> None:
     """Inert marker folded into op fingerprints via
-    ``functools.partial(_backend_tag, <backend>, <demotions>)`` — the
-    partial's args make backends (and runtime demotion states) produce
-    distinct cone fingerprints."""
+    ``functools.partial(_backend_tag, <backend>)`` — the partial's
+    argument makes the backends produce distinct cone fingerprints."""
 
 
 def _tag():
-    return functools.partial(_backend_tag, "pallas",
-                             tuple(sorted(_demoted)))
+    return functools.partial(_backend_tag, "pallas")
 
 
 _JOIN_NUMPY = (vkernels.combine_hashes, vkernels.hash_fixed,
@@ -318,7 +315,7 @@ _GROUP_BY_NUMPY = (vkernels.group_ranges, vkernels.grouped_count,
 
 def fp_includes_join():
     """Kernel deps of the join/filter_join ops, evaluated at fingerprint
-    time: numpy always; plus the backend tag and live Pallas kernels
+    time: numpy always; plus the backend tag and live device kernels
     when the pallas backend is active (so backend flips and kernel edits
     invalidate exactly the join cones)."""
     deps = _JOIN_NUMPY
@@ -340,22 +337,21 @@ def fp_includes_group_by():
 
 
 # --------------------------------------------------------------------------
-# in-process differential: demote anything whose bits diverge
+# in-process differential: raise on anything whose bits diverge
 # --------------------------------------------------------------------------
 
 def self_check(n: int = 4096, n_groups: int = 97) -> Dict[str, str]:
     """Compact differential over adversarial seeded inputs: every
-    *eligible* registry entry runs pallas-vs-numpy and must match bit
-    for bit (values and dtypes).  A mismatch demotes the kernel — calls
-    fall back to numpy — and is reported; ineligible entries report
-    their documented reason.  The ``bench_pallas_join`` smoke gate
-    asserts no demotions, so a regression fails CI even before the full
-    harness runs."""
-    results: Dict[str, str] = {}
+    *eligible* registry entry runs device-vs-numpy and must match bit
+    for bit (values and dtypes).  Any mismatch raises ``RuntimeError``
+    naming every diverging registry key; otherwise returns ``{key:
+    "ok"}`` for the eligible entries and ``{key: "ineligible: <reason>"}``
+    for the documented refusals.  The kernels run on jax's default
+    device, so on the chip this checks the compiled TPU programs."""
     if _pallas() is None:
         raise RuntimeError(
-            f"self_check needs the Pallas kernels; import failed with "
-            f"{_pallas_error!r} (see {_ENV})")
+            f"self_check needs the device kernels; import failed with "
+            f"{_pallas_error!r} (see {_ENV})") from _pallas_error
     rel = _pallas_mod
     rng = np.random.default_rng(0)
     f64 = rng.standard_normal(n)
@@ -374,6 +370,7 @@ def self_check(n: int = 4096, n_groups: int = 97) -> Dict[str, str]:
     sel = np.nonzero(rng.random(n // 2) < 0.5)[0]
     idx = rng.integers(-1, len(sel), n).astype(np.int64)
     pidx = rng.integers(-1, n, n).astype(np.int64)
+    results: Dict[str, str] = {}
 
     def check(key: str, got, want) -> None:
         gv, gc = got if isinstance(got, tuple) else (got, None)
@@ -382,13 +379,10 @@ def self_check(n: int = 4096, n_groups: int = 97) -> Dict[str, str]:
                 and np.array_equal(gv, wv, equal_nan=True)
                 and (gc is None or (gc.dtype == wc.dtype
                                     and np.array_equal(gc, wc))))
-        if same:
-            results.setdefault(key, "ok")
-        else:
-            why = f"bit mismatch vs numpy ({gv.dtype} vs {wv.dtype})"
-            demote(key, why)
-            results[key] = f"demoted: {why}"
-
+        if not same:
+            results[key] = (f"bit mismatch vs numpy "
+                            f"({gv.dtype} vs {wv.dtype})")
+        results.setdefault(key, "ok")
     for v in cols.values():
         check("hash_fixed", rel.hash_fixed(v), vkernels.hash_fixed(v))
     hs = [vkernels.hash_fixed(cols["int64"]),
@@ -414,6 +408,10 @@ def self_check(n: int = 4096, n_groups: int = 97) -> Dict[str, str]:
               vkernels.grouped_min(v, order, starts, valid))
         check("grouped_max:int", rel.grouped_max(v, order, starts, valid),
               vkernels.grouped_max(v, order, starts, valid))
+    bad = {k: v for k, v in results.items() if v != "ok"}
+    if bad:
+        raise RuntimeError(f"device kernels lost bit-identity with the "
+                           f"numpy reference: {bad}")
     for key, e in REGISTRY.items():
         if not e.eligible:
             results[key] = f"ineligible: {e.reason}"

@@ -48,7 +48,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from ..dag import (CACHED, COMPLETE, DAG, DONE, EVICTED, NodeState, RUNNING,
                    Sandbox, WAITING)
 from ..fingerprint import fingerprint_dag
-from .. import zarquet
+from .. import kdispatch, zarquet
 
 #: sentinel: nothing runnable now, but in-flight nodes may unblock us
 _WAIT = object()
@@ -687,6 +687,16 @@ class WorkerPoolExecutor:
         self.drain(timeout=30.0)
 
 
+def _refuse_device_backend() -> None:
+    if kdispatch.requested_backend() == "pallas":
+        raise ValueError(
+            "ProcessWorkerExecutor cannot serve ZERROW_KERNEL_BACKEND="
+            "pallas: the chip belongs to this process and Flight workers "
+            "run on the CPU (JAX_PLATFORMS=cpu). Use the thread executor "
+            "(workers_mode='thread') for device kernels, or "
+            "ZERROW_KERNEL_BACKEND=numpy.")
+
+
 class ProcessWorkerExecutor(WorkerPoolExecutor):
     """Executor whose node ops run in spawned OS worker processes.
 
@@ -706,10 +716,17 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
     functions must be picklable (module-level functions or
     ``functools.partial`` over them); unpicklable ops fall back to
     inline execution (counted in ``fallback_inline``).
+
+    Workers run on the host only (spawned with ``JAX_PLATFORMS=cpu``):
+    a chip belongs to one process, so this executor refuses the device
+    kernel backend (``ZERROW_KERNEL_BACKEND=pallas``) rather than run
+    the relational kernels on worker CPUs; device pipelines use the
+    thread executor.
     """
 
     def __init__(self, store, rm, workers: Optional[int] = None,
                  data_root: Optional[str] = None):
+        _refuse_device_backend()
         super().__init__(store, rm, workers, force_threads=True)
         if store.backing != "file":
             raise ValueError(
@@ -745,6 +762,7 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
     # -- pool lifecycle -----------------------------------------------------
     def _ensure_pool(self):
         if self._pool is None:
+            _refuse_device_backend()
             from ..flight.worker import FlightWorkerPool
             self._pool = FlightWorkerPool(
                 self.workers, sipc_mode=self.rm.cfg.sipc_mode,

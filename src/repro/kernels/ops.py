@@ -2,7 +2,7 @@
 
 ``interpret`` resolves *per call* (not at trace time):
 ``ZERROW_PALLAS_INTERPRET=0/1`` is the explicit, tested override;
-without it, kernels interpret everywhere except on a real TPU backend.
+without it, kernels interpret only on the CPU backend.
 The wrappers here are plain functions that resolve the mode and pass it
 as a static argument into the jitted implementations, so flipping the
 env var between calls takes effect immediately (a mode baked into a jit
@@ -53,7 +53,9 @@ def default_interpret() -> bool:
     override ``ZERROW_PALLAS_INTERPRET=1`` (force interpret) / ``0``
     (force compiled) wins and is read per call, so tests and CI lanes
     can flip it without reimporting; any other value raises.  Without
-    the override: interpret everywhere except on a TPU backend."""
+    the override: interpret only on the CPU backend, which cannot run a
+    compiled Pallas kernel — any accelerator compiles, and a kernel it
+    refuses fails loudly instead of falling back to the interpreter."""
     v = os.environ.get(_INTERPRET_ENV)
     if v:
         if v not in ("0", "1"):
@@ -61,7 +63,7 @@ def default_interpret() -> bool:
                 f"{_INTERPRET_ENV}={v!r}: use '1' (force interpret "
                 "mode) or '0' (force compiled)")
         return v == "1"
-    return not on_tpu()
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq",

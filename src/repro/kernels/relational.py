@@ -1,114 +1,141 @@
-"""Accelerator-resident relational kernels — Pallas ports of the
-eligible ``core/vkernels.py`` hot paths.
+"""Device-resident relational kernels: the eligible ``core/vkernels.py``
+hot paths as jitted XLA programs on jax's default device.
 
-The Zerrow data plane keeps relational data adjacent to the model
-kernels in this package (flash_attention, wkv6, take_gather); these
-kernels let the join/group-by hot path run *there* instead of
-round-tripping through host numpy.  Every kernel has an interpret-mode
-path (``ops.default_interpret()``, overridable with
-``ZERROW_PALLAS_INTERPRET``) so the whole surface runs in CI on
-accelerator-less runners, and every kernel is held to a **bit-identity**
-contract against its numpy reference — admission is decided by the
-differential harness in ``tests/test_pallas_relational.py`` and recorded
-in ``core.kdispatch.REGISTRY``; anything that cannot reproduce the numpy
+``ZERROW_KERNEL_BACKEND=pallas`` (``core/kdispatch.py``) routes the
+join/group-by hot path here, so it runs on the accelerator instead of
+host numpy.  Every kernel is held to a **bit-identity** contract against
+its numpy reference — admission is decided by the differential harness
+in ``tests/test_pallas_relational.py`` and recorded in
+``core.kdispatch.REGISTRY``; anything that cannot reproduce the numpy
 bits exactly (float segment sums: PR 5's sequential-accumulation
 contract) stays on numpy *by registry*, never silently.
 
-Ported kernels (signatures mirror ``core/vkernels.py``; inputs and
-outputs are plain numpy arrays, conversion happens at the edge):
+Why XLA and not Pallas: every kernel here works in 64-bit lanes
+(splitmix64 over uint64, int64 gather indices, int64 accumulators), and
+Mosaic, the TPU's Pallas compiler, has no tiling for 64-bit block
+dtypes; the gathers also read data-dependent rows from a source far
+larger than VMEM.  XLA emulates 64-bit integers on the TPU and gathers
+from HBM, so the jitted forms below compile for the chip
+(``tests/test_tpu_compile.py``) and run there.
+
+Each kernel has two parts:
+
+  * a *core* (``_hash_core``, ``_combine_core``, ``_hash_keys_core``,
+    ``_gather_core``, ``_segreduce_core``): a jittable function of
+    arrays and static options, which the compile tests lower from shapes
+    alone;
+  * a numpy *edge* (the public functions, signatures mirroring
+    ``core/vkernels.py``): it views the inputs' raw bits, pads them to a
+    bucketed length (``_bucket``) so a new row count rarely means a new
+    compile, runs the jitted core under scoped 64-bit mode, and returns
+    numpy arrays.
+
+Ported kernels:
 
   ``hash_fixed(v)``        splitmix64 over the bit patterns of a
-      fixed-width array (float -0.0 canonicalized, same prep as numpy);
-      the mix itself runs as a blocked elementwise Pallas kernel.
+      fixed-width array (float -0.0 canonicalized, as numpy does).
   ``combine_hashes(hs,n)`` order-sensitive fold of per-column uint64
       hashes into one row hash (the representation-free combiner).
-  ``hash_keys(keys, n)``   fused multi-key mixing: per-column splitmix64
-      + the ordered combine in ONE kernel over the stacked key bits.
-      Fixed-width key buffers only — var-length (offsets, values) keys
-      are structurally routed to numpy by the dispatch layer.
+  ``hash_keys(keys, n)``   per-column splitmix64 + the ordered combine in
+      one program over fixed-width key buffers; var-length (offsets,
+      values) keys are routed to numpy by the dispatch layer.
   ``filter_join_gather(sel, idx)``  compose a filter selection with join
-      gather indices, ``-1`` miss sentinels preserved, as a blocked
-      in-VMEM gather.
-  ``gather_payload(values, idx, fill=0)``  the fused payload-column
-      gather behind the join output: out[i] = values[idx[i]] with ``-1``
-      rows filled — ``take_rows``'s 1-D sibling with sentinel handling.
+      gather indices, ``-1`` miss sentinels preserved.
+  ``gather_payload(values, idx, fill=0)``  ``out[i] = values[idx[i]]``
+      with ``-1`` rows filled — the join output's payload gather.
   ``grouped_count / grouped_sum / grouped_min / grouped_max(values,
-  order, starts, valid=None)``  segment reducers over precomputed
-      ``group_ranges`` boundaries: a (row-block x group-block) one-hot
-      mask reduction with the group axis outermost, so each group
-      block's accumulator stays resident across the whole row sweep
-      (the flash-attention revisit pattern).  Integer/bool reductions
-      are exact in any order and hold bit-identity; float sum/min/max
-      are order- or tie-sensitive and are registry-ineligible.
+  order, starts, valid=None)``  segment reducers over ``group_ranges``
+      boundaries: rows gathered into group order, segment ids from the
+      starts, one sorted scatter-reduce.  Integer/bool reductions are
+      exact in any order and hold bit-identity; float sum/min/max are
+      order- or tie-sensitive and are registry-ineligible.
 
-64-bit lanes: the hash kernels work in uint64, so calls run under
-``jax.experimental.enable_x64`` (scoped — the global default-dtype
-behavior of the model kernels is untouched).  TPU note: 64-bit integer
-lanes are emulated on current TPUs; the interpret path is the CI
-contract, the compiled path is gated by the same differential harness
-before it may be admitted on real hardware.
-
-The kernel *bodies* are plain module functions referenced directly by
-the public wrappers (no module-level jit objects), so
-``core.fingerprint``'s direct-global scan sees them: editing a kernel
-body here invalidates every cached join/group-by cone that was computed
-with ``ZERROW_KERNEL_BACKEND=pallas``.
+The cores are plain module functions, jitted at the call (jax reuses the
+compiled program for the same function, options and shapes) rather than
+held as module-level jit objects, so ``core.fingerprint``'s direct-
+global scan sees their code: editing a kernel here invalidates every
+cached join/group-by cone computed with ``ZERROW_KERNEL_BACKEND=pallas``.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
-from jax.experimental import pallas as pl
 
+from repro.compat import enable_x64
 from repro.core import vkernels
-
-from .ops import default_interpret
 
 __all__ = [
     "hash_fixed", "combine_hashes", "hash_keys",
     "filter_join_gather", "gather_payload",
     "grouped_count", "grouped_sum", "grouped_min", "grouped_max",
+    "platform",
 ]
 
 _GOLDEN = np.uint64(0x9E3779B97F4A7C15)
 
-#: elementwise/gather block rows and (row, group) reduction tile —
-#: VPU-lane friendly multiples; interpret mode only cares that the tile
-#: bounds the (block x group-block) one-hot materialization
-_BN = 2048
-_BG = 512
+#: smallest padded length: tiny inputs share one compiled shape
+_MIN_ROWS = 1024
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
-
-
-def _pad1(a: np.ndarray, bn: int, fill) -> Tuple[np.ndarray, int]:
-    """Pad a 1-D array up to a block multiple (at least one block)."""
-    n = len(a)
-    npad = -(-max(n, 1) // bn) * bn
-    if npad == n:
-        return a, n
-    out = np.full(npad, fill, dtype=a.dtype)
-    out[:n] = a
-    return out, n
+def platform() -> str:
+    """Platform of the device the kernels run on (jax's default)."""
+    return jax.devices()[0].platform
 
 
 # --------------------------------------------------------------------------
-# splitmix64: hash_fixed / combine_hashes / fused hash_keys
+# the numpy edge: bucketed padding and the jitted call
+# --------------------------------------------------------------------------
+
+def _bucket(n: int) -> int:
+    """Padded length for ``n`` rows: at least ``_MIN_ROWS``, otherwise
+    ``n`` rounded up to its three leading bits — padding wastes at most
+    an eighth, and row counts share a few compiled shapes."""
+    n = max(n, _MIN_ROWS)
+    q = 1 << (n.bit_length() - 3)
+    return -(-n // q) * q
+
+
+def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
+    """``a`` extended with ``fill`` to ``size`` rows."""
+    if len(a) == size:
+        return a
+    out = np.full(size, fill, dtype=a.dtype)
+    out[:len(a)] = a
+    return out
+
+
+def _call(core, args, static):
+    """``jax.jit(core)(*args, **static)`` on the default device under
+    64-bit mode, outputs copied back as numpy arrays."""
+    with enable_x64():
+        out = jax.jit(core, static_argnames=tuple(static))(*args, **static)
+        return jax.tree.map(np.asarray, out)
+
+
+def _bits(values: np.ndarray) -> Tuple[np.ndarray, int]:
+    """(unsigned view of the raw bits, the float sign-bit pattern or 0):
+    the representation half of ``vkernels.hash_fixed``'s prep — the
+    -0.0 canonicalization and the widening run in ``_hash_core``."""
+    values = np.ascontiguousarray(values)
+    w = values.dtype.itemsize
+    sign = 1 << (8 * w - 1) if np.issubdtype(values.dtype, np.floating) \
+        else 0
+    return values.view(f"u{w}"), sign
+
+
+# --------------------------------------------------------------------------
+# splitmix64: hash_fixed / combine_hashes / hash_keys
 # --------------------------------------------------------------------------
 
 def _mix64(h):
-    """splitmix64 finalizer over a uint64 jnp array — the same constants
-    and operation order as ``vkernels._mix64`` (wrapping uint64 multiply
-    and xor-shift are exact on any backend, so bits agree)."""
+    """splitmix64 finalizer over a uint64 array — the same constants and
+    operation order as ``vkernels._mix64`` (wrapping uint64 multiply and
+    xor-shift are exact on any backend, so bits agree)."""
     h = h ^ (h >> jnp.uint64(30))
     h = h * jnp.uint64(0xBF58476D1CE4E5B9)
     h = h ^ (h >> jnp.uint64(27))
@@ -116,253 +143,165 @@ def _mix64(h):
     return h ^ (h >> jnp.uint64(31))
 
 
-def _prep_bits(values: np.ndarray) -> np.ndarray:
-    """Bit-pattern prep, shared contract with ``vkernels.hash_fixed``:
-    canonicalize float -0.0 to +0.0, then widen the raw bits to uint64.
-    Pure representation work (views + one elementwise where) — the
-    mixing is what runs on the accelerator."""
-    values = np.ascontiguousarray(values)
-    if np.issubdtype(values.dtype, np.floating):
-        values = np.where(values == 0, 0, values)
-    w = values.dtype.itemsize
-    return np.ascontiguousarray(values).view(f"u{w}").astype(np.uint64) \
-        if w < 8 else np.ascontiguousarray(values).view(np.uint64)
+def _hash_core(bits, *, sign: int):
+    """splitmix64 of each element's bits.  ``sign`` is the float
+    sign-bit pattern (the bits of -0.0, which hash as +0.0) or 0 for
+    integer bits; narrower bits zero-extend to uint64."""
+    if sign:
+        bits = jnp.where(bits == bits.dtype.type(sign),
+                         jnp.zeros_like(bits), bits)
+    return _mix64(bits.astype(jnp.uint64) ^ jnp.uint64(_GOLDEN))
 
 
-def _hash_fixed_kernel(bits_ref, out_ref):
-    out_ref[...] = _mix64(bits_ref[...] ^ jnp.uint64(_GOLDEN))
+def _combine_core(hashes):
+    """Ordered fold of a tuple of uint64 column hashes into row hashes."""
+    h = jnp.full(hashes[0].shape, _GOLDEN, jnp.uint64)
+    for hk in hashes:
+        h = _mix64(h * jnp.uint64(_GOLDEN) ^ hk)
+    return h
 
 
-def hash_fixed(values: np.ndarray, *,
-               interpret: Optional[bool] = None) -> np.ndarray:
+def _hash_keys_core(bits, *, signs: Tuple[int, ...]):
+    """``_hash_core`` per key column, then the ordered combine."""
+    return _combine_core(tuple(_hash_core(b, sign=s)
+                               for b, s in zip(bits, signs)))
+
+
+def hash_fixed(values: np.ndarray) -> np.ndarray:
     """uint64 splitmix64 hash per element of a fixed-width array —
     bit-identical to ``vkernels.hash_fixed``."""
-    bits = _prep_bits(values)
+    bits, sign = _bits(values)
     n = len(bits)
     if n == 0:
         return np.empty(0, dtype=np.uint64)
-    padded, _ = _pad1(bits, _BN, 0)
-    with enable_x64():
-        out = pl.pallas_call(
-            _hash_fixed_kernel,
-            grid=(len(padded) // _BN,),
-            in_specs=[pl.BlockSpec((_BN,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((_BN,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((len(padded),), jnp.uint64),
-            interpret=_resolve_interpret(interpret),
-        )(padded)
-        return np.asarray(out)[:n]
+    return _call(_hash_core, (_pad(bits, _bucket(n), 0),),
+                 {"sign": sign})[:n]
 
 
-def _combine_kernel(hs_ref, out_ref, *, ncols, bn):
-    h = jnp.full((bn,), jnp.uint64(_GOLDEN))
-    for j in range(ncols):
-        h = _mix64(h * jnp.uint64(_GOLDEN) ^ hs_ref[j, :])
-    out_ref[...] = h
-
-
-def _run_combine(stacked: np.ndarray, n: int, mix_first: bool,
-                 interpret: Optional[bool]) -> np.ndarray:
-    """Shared driver for ``combine_hashes`` (pre-hashed columns) and the
-    fused ``hash_keys`` (raw bits, ``mix_first=True`` hashes each column
-    in-kernel before the ordered combine)."""
-    ncols = stacked.shape[0]
-    npad = -(-max(n, 1) // _BN) * _BN
-    if npad != n:
-        padded = np.zeros((ncols, npad), dtype=np.uint64)
-        padded[:, :n] = stacked
-    else:
-        padded = stacked
-    kernel = functools.partial(
-        _hash_keys_kernel if mix_first else _combine_kernel,
-        ncols=ncols, bn=_BN)
-    with enable_x64():
-        out = pl.pallas_call(
-            kernel,
-            grid=(npad // _BN,),
-            in_specs=[pl.BlockSpec((ncols, _BN), lambda i: (0, i))],
-            out_specs=pl.BlockSpec((_BN,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((npad,), jnp.uint64),
-            interpret=_resolve_interpret(interpret),
-        )(padded)
-        return np.asarray(out)[:n]
-
-
-def combine_hashes(col_hashes: Sequence[np.ndarray], n: int, *,
-                   interpret: Optional[bool] = None) -> np.ndarray:
+def combine_hashes(col_hashes: Sequence[np.ndarray], n: int) -> np.ndarray:
     """Fold per-column uint64 hash arrays into one row hash —
     bit-identical to ``vkernels.combine_hashes`` (order-sensitive)."""
     if not col_hashes or n == 0:
         return vkernels.combine_hashes(col_hashes, n)
-    stacked = np.ascontiguousarray(
-        np.stack([np.asarray(h, dtype=np.uint64) for h in col_hashes]))
-    return _run_combine(stacked, n, mix_first=False, interpret=interpret)
+    size = _bucket(n)
+    hs = tuple(_pad(np.asarray(h, dtype=np.uint64), size, 0)
+               for h in col_hashes)
+    return _call(_combine_core, (hs,), {})[:n]
 
 
-def _hash_keys_kernel(bits_ref, out_ref, *, ncols, bn):
-    h = jnp.full((bn,), jnp.uint64(_GOLDEN))
-    for j in range(ncols):
-        hk = _mix64(bits_ref[j, :] ^ jnp.uint64(_GOLDEN))
-        h = _mix64(h * jnp.uint64(_GOLDEN) ^ hk)
-    out_ref[...] = h
-
-
-def hash_keys(keys: Sequence[np.ndarray], n: int, *,
-              interpret: Optional[bool] = None) -> np.ndarray:
-    """Fused multi-key mixing over *fixed-width* key buffers: per-column
-    splitmix64 and the ordered combine in one kernel pass —
-    bit-identical to ``vkernels.hash_keys`` on ndarray keys.  Var-length
-    ``(offsets, values)`` keys are not expressible here; the dispatch
-    layer routes any mix containing one to numpy."""
+def hash_keys(keys: Sequence[np.ndarray], n: int) -> np.ndarray:
+    """Per-column splitmix64 and the ordered combine in one program over
+    *fixed-width* key buffers — bit-identical to ``vkernels.hash_keys``
+    on ndarray keys.  Var-length ``(offsets, values)`` keys are not
+    expressible here; the dispatch layer routes any mix containing one
+    to numpy."""
     if any(isinstance(k, tuple) for k in keys):
-        raise TypeError("hash_keys (pallas) takes fixed-width ndarray "
+        raise TypeError("hash_keys (device) takes fixed-width ndarray "
                         "keys only; var-length keys stay on numpy")
     if not keys or n == 0:
         return vkernels.hash_keys(list(keys), n)
-    stacked = np.ascontiguousarray(
-        np.stack([_prep_bits(k) for k in keys]))
-    return _run_combine(stacked, n, mix_first=True, interpret=interpret)
+    size = _bucket(n)
+    bits, signs = zip(*(_bits(k) for k in keys))
+    return _call(_hash_keys_core,
+                 (tuple(_pad(b, size, 0) for b in bits),),
+                 {"signs": tuple(signs)})[:n]
 
 
 # --------------------------------------------------------------------------
-# gathers: filter->join index composition + fused payload gather
+# gathers: filter->join index composition + payload gather
 # --------------------------------------------------------------------------
 
-def _gather_kernel(idx_ref, src_ref, out_ref, *, fill):
-    idx = idx_ref[...]
+def _gather_core(src, idx, fill):
+    """``out[i] = src[idx[i]]``, with ``fill`` where ``idx[i] < 0``."""
     hit = idx >= 0
-    g = jnp.take(src_ref[...], jnp.where(hit, idx, 0), axis=0)
-    out_ref[...] = jnp.where(hit, g, jnp.asarray(fill, g.dtype))
+    g = jnp.take(src, jnp.where(hit, idx, 0), axis=0, mode="clip")
+    return jnp.where(hit, g, fill)
 
 
-def _sentinel_gather(src: np.ndarray, idx: np.ndarray, fill, *,
-                     interpret: Optional[bool]) -> np.ndarray:
-    """Blocked gather with the whole source resident per block and ``-1``
-    indices mapped to ``fill`` — the shared core of
-    ``filter_join_gather`` and ``gather_payload``."""
+def _sentinel_gather(src: np.ndarray, idx: np.ndarray, fill) -> np.ndarray:
+    """The shared edge of ``filter_join_gather`` and ``gather_payload``."""
     m = len(idx)
     if m == 0:
         return np.empty(0, dtype=src.dtype)
     if len(src) == 0:
         # every index must be a -1 miss sentinel; nothing to gather
         return np.full(m, fill, dtype=src.dtype)
-    ii, _ = _pad1(np.ascontiguousarray(idx, dtype=np.int64), _BN, -1)
-    r = len(src)
-    with enable_x64():
-        out = pl.pallas_call(
-            functools.partial(_gather_kernel, fill=fill),
-            grid=(len(ii) // _BN,),
-            in_specs=[pl.BlockSpec((_BN,), lambda i: (i,)),
-                      pl.BlockSpec((r,), lambda i: (0,))],
-            out_specs=pl.BlockSpec((_BN,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((len(ii),), src.dtype),
-            interpret=_resolve_interpret(interpret),
-        )(ii, np.ascontiguousarray(src))
-        return np.asarray(out)[:m]
+    return _call(_gather_core,
+                 (_pad(src, _bucket(len(src)), 0),
+                  _pad(np.asarray(idx, dtype=np.int64), _bucket(m), -1),
+                  np.asarray(fill, dtype=src.dtype)), {})[:m]
 
 
-def filter_join_gather(sel: np.ndarray, idx: np.ndarray, *,
-                       interpret: Optional[bool] = None) -> np.ndarray:
+def filter_join_gather(sel: np.ndarray, idx: np.ndarray) -> np.ndarray:
     """Compose a filter's row selection with a join's gather indices,
     ``-1`` left-join miss sentinels preserved — bit-identical to
     ``vkernels.filter_join_gather``."""
-    sel = np.ascontiguousarray(sel, dtype=np.int64)
-    return _sentinel_gather(sel, idx, -1, interpret=interpret)
+    return _sentinel_gather(np.asarray(sel, dtype=np.int64), idx, -1)
 
 
-def gather_payload(values: np.ndarray, idx: np.ndarray, fill=0, *,
-                   interpret: Optional[bool] = None) -> np.ndarray:
-    """Fused join-payload gather: ``out[i] = values[idx[i]]`` with
+def gather_payload(values: np.ndarray, idx: np.ndarray,
+                   fill=0) -> np.ndarray:
+    """Join-payload gather: ``out[i] = values[idx[i]]`` with
     ``idx[i] == -1`` rows set to ``fill`` — the 1-D, sentinel-aware
-    sibling of ``take_rows`` used to materialize join output columns
-    without a host round-trip."""
-    values = np.ascontiguousarray(values)
-    return _sentinel_gather(values, np.asarray(idx, dtype=np.int64), fill,
-                            interpret=interpret)
+    sibling of ``take_rows`` used to materialize join output columns."""
+    return _sentinel_gather(np.asarray(values), idx, fill)
 
 
 # --------------------------------------------------------------------------
 # segment reducers over group_ranges boundaries
 # --------------------------------------------------------------------------
 
-def _segreduce_kernel(seg_ref, v_ref, w_ref, out_ref, cnt_ref, *,
-                      op, bn, bg, sentinel):
-    j = pl.program_id(0)                       # group block (outermost)
-    i = pl.program_id(1)                       # row block (innermost)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[...] = jnp.full((bg,), jnp.asarray(sentinel,
-                                                   out_ref.dtype))
-        cnt_ref[...] = jnp.zeros((bg,), cnt_ref.dtype)
-
-    seg = seg_ref[...]
-    gids = jax.lax.broadcasted_iota(jnp.int32, (bn, bg), 1) + j * bg
-    oh = seg[:, None] == gids
-    v = v_ref[...][:, None]
-    w = w_ref[...][:, None]
-    cnt_ref[...] += jnp.sum(jnp.where(oh, w, 0), axis=0)
+def _segreduce_core(values, order, starts, valid, n, *, op: str):
+    """(reduced, counts) per group.  ``order``/``starts`` are
+    ``vkernels.group_ranges`` output and ``values``/``valid`` are in
+    original row order, all padded: sorted positions ``>= n`` are
+    padding and drop out of every segment, and the groups past the real
+    ones are sliced off by the edge.  ``valid=None`` means no nulls;
+    ``values`` is unused (None) for a count.  All-null groups reduce to
+    the dtype's extreme, as ``reduceat`` over sentinel-filled rows does
+    in numpy."""
+    rows, groups = order.shape[0], starts.shape[0]
+    pos = jnp.arange(rows)
+    seg = jnp.searchsorted(starts, pos, side="right") - 1
+    seg = jnp.where(pos < n, seg, groups)
+    w = None if valid is None else valid[order]
+    ones = jnp.ones(rows, jnp.int64) if w is None else w.astype(jnp.int64)
+    counts = jax.ops.segment_sum(ones, seg, groups, indices_are_sorted=True)
+    if op == "count":
+        return counts, counts
+    v = values[order]
     if op == "sum":
-        out_ref[...] += jnp.sum(jnp.where(oh, v, 0), axis=0)
-    elif op == "min":
-        out_ref[...] = jnp.minimum(
-            out_ref[...],
-            jnp.min(jnp.where(oh, v, jnp.asarray(sentinel, v.dtype)),
-                    axis=0))
-    else:
-        out_ref[...] = jnp.maximum(
-            out_ref[...],
-            jnp.max(jnp.where(oh, v, jnp.asarray(sentinel, v.dtype)),
-                    axis=0))
+        v = v.astype(jnp.uint64 if v.dtype == jnp.uint64 else jnp.int64)
+        if w is not None:
+            v = jnp.where(w, v, jnp.zeros_like(v))
+        return jax.ops.segment_sum(v, seg, groups,
+                                   indices_are_sorted=True), counts
+    info = jnp.iinfo(v.dtype)
+    if w is not None:
+        v = jnp.where(w, v, jnp.full_like(
+            v, info.max if op == "min" else info.min))
+    reduce = jax.ops.segment_min if op == "min" else jax.ops.segment_max
+    return reduce(v, seg, groups, indices_are_sorted=True), counts
 
 
-def _segreduce(op: str, seg: np.ndarray, v: np.ndarray, w: np.ndarray,
-               n_groups: int, sentinel, interpret: Optional[bool]
-               ) -> Tuple[np.ndarray, np.ndarray]:
-    """(reduced, counts) over dense sorted-domain segment ids.  Grid is
-    (group blocks, row blocks) with the group axis outermost, so each
-    group block's accumulator is revisited on consecutive steps only —
-    resident across the whole row sweep, no partial spills."""
-    seg_p, _ = _pad1(np.ascontiguousarray(seg, np.int32), _BN, -1)
-    v_p, _ = _pad1(np.ascontiguousarray(v), _BN, 0)
-    w_p, _ = _pad1(np.ascontiguousarray(w, np.int64), _BN, 0)
-    gpad = -(-n_groups // _BG) * _BG
-    kernel = functools.partial(_segreduce_kernel, op=op, bn=_BN, bg=_BG,
-                               sentinel=sentinel)
-    with enable_x64():
-        out, cnt = pl.pallas_call(
-            kernel,
-            grid=(gpad // _BG, len(seg_p) // _BN),
-            in_specs=[pl.BlockSpec((_BN,), lambda j, i: (i,)),
-                      pl.BlockSpec((_BN,), lambda j, i: (i,)),
-                      pl.BlockSpec((_BN,), lambda j, i: (i,))],
-            out_specs=[pl.BlockSpec((_BG,), lambda j, i: (j,)),
-                       pl.BlockSpec((_BG,), lambda j, i: (j,))],
-            out_shape=[jax.ShapeDtypeStruct((gpad,), v_p.dtype),
-                       jax.ShapeDtypeStruct((gpad,), jnp.int64)],
-            interpret=_resolve_interpret(interpret),
-        )(seg_p, v_p, w_p)
-        return (np.asarray(out)[:n_groups], np.asarray(cnt)[:n_groups])
-
-
-def _sorted_segments(order: np.ndarray, starts: np.ndarray
-                     ) -> np.ndarray:
-    """Dense int32 group ids in the sorted domain (boundary metadata,
-    linear-time host prep — the reduction itself is the kernel)."""
-    n = len(order)
-    counts = np.diff(np.append(starts, n))
-    return np.repeat(np.arange(len(starts), dtype=np.int32), counts)
-
-
-def _weights(order: np.ndarray, valid) -> np.ndarray:
-    if valid is None:
-        return np.ones(len(order), dtype=np.int64)
-    return valid[order].astype(np.int64)
+def _segreduce(op: str, values, order: np.ndarray, starts: np.ndarray,
+               valid) -> Tuple[np.ndarray, np.ndarray]:
+    """The reducers' shared edge: pad to bucketed row and group counts,
+    run ``_segreduce_core``, slice the real groups."""
+    n, g = len(order), len(starts)
+    rows, groups = _bucket(n), _bucket(g)
+    vals, counts = _call(_segreduce_core, (
+        None if values is None else _pad(values, rows, 0),
+        _pad(np.asarray(order, dtype=np.int64), rows, 0),
+        _pad(np.asarray(starts, dtype=np.int64), groups, n),
+        None if valid is None else _pad(np.asarray(valid, dtype=bool),
+                                        rows, False),
+        np.int64(n)), {"op": op})
+    return vals[:g], counts[:g]
 
 
 def grouped_count(values: np.ndarray, order: np.ndarray,
-                  starts: np.ndarray, valid=None, *,
-                  interpret: Optional[bool] = None
+                  starts: np.ndarray, valid=None
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-group count of non-null rows — bit-identical to
     ``vkernels.grouped_count`` (``values`` ignored, uniform reducer
@@ -370,18 +309,15 @@ def grouped_count(values: np.ndarray, order: np.ndarray,
     if len(starts) == 0:
         counts = np.empty(0, np.int64)
         return counts, counts
-    seg = _sorted_segments(order, starts)
-    w = _weights(order, valid)
-    _, counts = _segreduce("sum", seg, w, w, len(starts), 0, interpret)
+    _, counts = _segreduce("count", None, order, starts, valid)
     return counts, counts
 
 
 def grouped_sum(values: np.ndarray, order: np.ndarray,
-                starts: np.ndarray, valid=None, *,
-                interpret: Optional[bool] = None
+                starts: np.ndarray, valid=None
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-group sum over non-null rows, integer/bool inputs only —
-    exact in any accumulation order, so blocked reduction holds
+    exact in any accumulation order, so the scatter-add holds
     bit-identity with ``vkernels.grouped_sum``'s ``reduceat``.  Float
     inputs are rejected: PR 5's contract accumulates float sums
     sequentially in original row order (``np.bincount``), which a
@@ -390,53 +326,38 @@ def grouped_sum(values: np.ndarray, order: np.ndarray,
     if not (values.dtype == np.bool_
             or np.issubdtype(values.dtype, np.integer)):
         raise TypeError(
-            f"grouped_sum (pallas) is integer/bool only; {values.dtype} "
+            f"grouped_sum (device) is integer/bool only; {values.dtype} "
             "sums are order-sensitive and registry-ineligible")
-    acc = np.uint64 if values.dtype == np.uint64 else np.int64
-    n_groups = len(starts)
-    if n_groups == 0:
+    if len(starts) == 0:
+        acc = np.uint64 if values.dtype == np.uint64 else np.int64
         return np.empty(0, acc), np.empty(0, np.int64)
-    seg = _sorted_segments(order, starts)
-    v = values[order].astype(acc)
-    if valid is not None:
-        v = np.where(valid[order], v, v.dtype.type(0))
-    sums, counts = _segreduce("sum", seg, v, _weights(order, valid),
-                              n_groups, 0, interpret)
-    return sums, counts
+    return _segreduce("sum", _int_view(values), order, starts, valid)
 
 
-def _grouped_extreme(op, values, order, starts, valid, sentinel_of,
-                     interpret):
+def _int_view(values: np.ndarray) -> np.ndarray:
+    """Bools as uint8 (vkernels reduces them so), integers unchanged."""
+    return values.view(np.uint8) if values.dtype == np.bool_ else values
+
+
+def _grouped_extreme(op, values, order, starts, valid):
     if np.issubdtype(values.dtype, np.floating):
         raise TypeError(
-            f"grouped_{op} (pallas) is integer/bool only; float "
+            f"grouped_{op} (device) is integer/bool only; float "
             "-0.0/NaN tie-breaking is order-sensitive and "
             "registry-ineligible")
-    n_groups = len(starts)
-    v = values[order]
-    if v.dtype == np.bool_:
-        v = v.astype(np.uint8)
-    if n_groups == 0:
+    v = _int_view(values)
+    if len(starts) == 0:
         return np.empty(0, v.dtype), np.empty(0, np.int64)
-    sentinel = sentinel_of(v.dtype)
-    if valid is not None:
-        v = np.where(valid[order], v, v.dtype.type(sentinel))
-    seg = _sorted_segments(order, starts)
-    return _segreduce(op, seg, v, _weights(order, valid), n_groups,
-                      sentinel, interpret)
+    return _segreduce(op, v, order, starts, valid)
 
 
-def grouped_min(values, order, starts, valid=None, *,
-                interpret: Optional[bool] = None):
+def grouped_min(values, order, starts, valid=None):
     """Per-group min over non-null rows (integer/bool) — bit-identical
     to ``vkernels.grouped_min`` including the all-null sentinel."""
-    return _grouped_extreme("min", values, order, starts, valid,
-                            vkernels._dtype_max, interpret)
+    return _grouped_extreme("min", values, order, starts, valid)
 
 
-def grouped_max(values, order, starts, valid=None, *,
-                interpret: Optional[bool] = None):
+def grouped_max(values, order, starts, valid=None):
     """Per-group max over non-null rows (integer/bool) — bit-identical
     to ``vkernels.grouped_max`` including the all-null sentinel."""
-    return _grouped_extreme("max", values, order, starts, valid,
-                            vkernels._dtype_min, interpret)
+    return _grouped_extreme("max", values, order, starts, valid)

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from ..configs import get_arch, smoke_variant
+from .compile_cache import enable_compile_cache
 from ..models.api import ModelAPI
 from ..serve.engine import (Request, ServeEngine, ZerrowPromptSource,
                             make_prompt_shards)
@@ -58,6 +59,7 @@ def main():
                          "re-launches adopt unchanged shards (CACHED) "
                          "instead of re-deserializing them")
     a = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(a.arch)
     if a.smoke:

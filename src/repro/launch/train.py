@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_arch, smoke_variant
+from .compile_cache import enable_compile_cache
 from ..data.pipeline import (PipelineConfig, ZerrowDataPipeline,
                              make_text_shards)
 from ..models.api import ModelAPI
@@ -121,6 +122,7 @@ def main():
                          "restarts adopt unchanged shards instead of "
                          "re-tokenizing (differential caching)")
     a = ap.parse_args()
+    enable_compile_cache()
     train_loop(a.arch, steps=a.steps, batch=a.batch, seq_len=a.seq_len,
                smoke=a.smoke, ckpt_dir=a.ckpt_dir, resume=a.resume,
                lr=a.lr, data_workers=a.data_workers,

@@ -312,6 +312,36 @@ def test_process_mode_unpicklable_fn_falls_back_inline(tmp_path):
         fstore.close()
 
 
+def _worker_jax_platforms(tables):
+    return Table.from_pydict(
+        {"jax_platforms": [os.environ.get("JAX_PLATFORMS", "<unset>")]})
+
+
+def test_workers_spawn_on_the_host_only(tmp_path, monkeypatch):
+    """Flight workers are spawned with JAX_PLATFORMS=cpu, so none can
+    take a chip the parent holds; the parent's own value is restored."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    paths = _write_shards(str(tmp_path), n=1)
+    fstore = _file_store(tmp_path)
+    rm = ResourceManager(fstore, RMConfig(workers=1,
+                                          workers_mode="process"))
+    ex = ProcessWorkerExecutor(fstore, rm, workers=1)
+    dag = DAG([
+        NodeSpec("load", source=paths[0], est_mem=1 << 22),
+        NodeSpec("env", fn=_worker_jax_platforms, deps=["load"],
+                 est_mem=1 << 22, keep_output=True),
+    ], name="env")
+    try:
+        ex.run([dag])
+        assert ex.fallback_inline == 0
+        t = SipcReader(fstore).read_table(dag.nodes["env"].output)
+        assert t.to_pydict() == {"jax_platforms": ["cpu"]}
+        assert os.environ["JAX_PLATFORMS"] == "tpu"
+    finally:
+        ex.close()
+        fstore.close()
+
+
 def test_process_mode_decache_shares_loads(tmp_path):
     """Two DAGs over the same source: the load runs once, in a worker."""
     paths = _write_shards(str(tmp_path), n=1)

@@ -226,11 +226,11 @@ def test_default_interpret_env_override(monkeypatch):
     monkeypatch.setenv("ZERROW_PALLAS_INTERPRET", "yes")
     with pytest.raises(ValueError, match="ZERROW_PALLAS_INTERPRET"):
         ops.default_interpret()
-    # unset (and empty) fall back to device sniffing
+    # unset (and empty): interpret only on the CPU backend
     monkeypatch.setenv("ZERROW_PALLAS_INTERPRET", "")
-    assert ops.default_interpret() == (not ops.on_tpu())
+    assert ops.default_interpret() == (jax.default_backend() == "cpu")
     monkeypatch.delenv("ZERROW_PALLAS_INTERPRET")
-    assert ops.default_interpret() == (not ops.on_tpu())
+    assert ops.default_interpret() == (jax.default_backend() == "cpu")
 
 
 def test_interpret_override_takes_effect_per_call(rng, monkeypatch):

@@ -1,4 +1,4 @@
-"""Eligibility harness for the Pallas relational kernels.
+"""Eligibility harness for the device relational kernels.
 
 Admission to the ``ZERROW_KERNEL_BACKEND=pallas`` path is decided here:
 every kernel in ``repro.kernels.relational`` must be **bit-identical**
@@ -8,15 +8,17 @@ must itself match a per-row naive Python reference — across dtype mixes
 duplicate-heavy keys, zero-row and empty(all-null)-group inputs.
 Kernels the registry marks ineligible (the order-sensitive float
 reductions) are asserted to *stay* on the numpy path even with the
-pallas backend active, and the demotion machinery is proven to pull a
-kernel whose bits regress.  The fingerprint section pins the cache
+pallas backend active, ``self_check`` is proven to raise on a kernel
+whose bits regress, and a backend that cannot load raises instead of
+serving numpy.  The fingerprint section pins the cache
 contract: flipping the backend or editing a Pallas kernel invalidates
 exactly the join/group-by cones (manifest adoption counts asserted,
 mirroring the PR 5 ``__fp_includes__`` tests).
 
-Everything here runs interpret-mode on accelerator-less CI runners; the
-seeded-numpy sweeps always run, and a hypothesis property (stress lane)
-widens the input space when hypothesis is installed.
+The kernels run on jax's default device: the CPU under
+``JAX_PLATFORMS=cpu`` here, the TPU on the chip.  The seeded-numpy sweeps
+always run, and a hypothesis property (stress lane) widens the input
+space when hypothesis is installed.
 """
 import functools
 import os
@@ -44,8 +46,6 @@ _M64 = (1 << 64) - 1
 @pytest.fixture
 def pallas_backend(monkeypatch):
     monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
-    yield
-    kd.reset_demotions()
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +130,7 @@ def assert_bits(got, want, msg=""):
 
 
 # ---------------------------------------------------------------------------
-# hashing: pallas-interpret == numpy == per-row python
+# hashing: device kernel == numpy == per-row python
 # ---------------------------------------------------------------------------
 
 _HASH_ARRAYS = {
@@ -336,60 +336,87 @@ def test_float_sum_dispatch_stays_numpy(pallas_backend):
 
 def test_self_check_admits_current_kernels(pallas_backend):
     res = kd.self_check()
-    demoted = {k: v for k, v in res.items() if v.startswith("demoted")}
-    assert not demoted, demoted
-    assert not kd.demoted()
+    eligible = [k for k, e in kd.REGISTRY.items() if e.eligible]
+    assert all(res[k] == "ok" for k in eligible), res
     assert res["hash_fixed"] == "ok"
     assert res["grouped_sum:int"] == "ok"
     assert res["grouped_sum:float"].startswith("ineligible")
 
 
-def test_self_check_demotes_bit_regressions(pallas_backend, monkeypatch):
-    """The registry rejects a kernel whose differential fails: corrupt
-    the pallas grouped_sum, prove self_check demotes it, dispatch falls
-    back to numpy bits, and the demotion changes the group_by cone
-    fingerprint (a demoted backend must not serve stale cones)."""
+def test_self_check_raises_on_bit_regressions(pallas_backend, monkeypatch):
+    """A kernel whose differential fails is not admitted: corrupt the
+    device grouped_sum, prove self_check raises naming it (no demotion,
+    no quiet numpy fallback), and that the edit changes the group_by
+    cone fingerprint."""
     fp_before = code_fingerprint(ops.group_by)
 
-    def bad_grouped_sum(values, order, starts, valid=None, **kw):
+    def bad_grouped_sum(values, order, starts, valid=None):
         vals, counts = vkernels.grouped_sum(values, order, starts, valid)
         return vals + vals.dtype.type(1), counts       # off by one
 
     monkeypatch.setattr(rel, "grouped_sum", bad_grouped_sum)
-    fp_patched = code_fingerprint(ops.group_by)
-    assert fp_patched != fp_before, "kernel edit must change the cone"
-    res = kd.self_check()
-    assert res["grouped_sum:int"].startswith("demoted")
-    assert "grouped_sum:int" in kd.demoted()
-    assert not kd.eligible("grouped_sum", np.int64)
-    # dispatch now serves numpy bits despite the corrupted kernel
-    rng = np.random.default_rng(6)
-    v = rng.integers(-50, 50, 500).astype(np.int64)
-    order, starts = vkernels.group_ranges([rng.integers(0, 9, 500)])
-    assert_bits(kd.GROUPED_REDUCERS["sum"](v, order, starts),
-                vkernels.grouped_sum(v, order, starts))
-    assert code_fingerprint(ops.group_by) != fp_patched, \
-        "a demotion must change the cone (numpy now computes it)"
-    kd.reset_demotions()
-    assert kd.eligible("grouped_sum", np.int64)
-    assert code_fingerprint(ops.group_by) == fp_patched
+    assert code_fingerprint(ops.group_by) != fp_before, \
+        "kernel edit must change the cone"
+    with pytest.raises(RuntimeError, match="grouped_sum:int"):
+        kd.self_check()
+    assert kd.eligible("grouped_sum", np.int64), \
+        "the registry is static: a failed check raises, it never demotes"
 
 
-def test_unavailable_pallas_warns_once_and_serves_numpy(monkeypatch):
+def test_unavailable_pallas_raises_naming_the_knob(monkeypatch):
     monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
     monkeypatch.setattr(kd, "_pallas_mod", None)
     monkeypatch.setattr(kd, "_pallas_error",
                         ImportError("jax not installed"))
-    monkeypatch.setattr(kd, "_warned_fallback", False)
     v = np.arange(10, dtype=np.int64)
-    with pytest.warns(RuntimeWarning, match="ZERROW_KERNEL_BACKEND"):
-        assert kd.active_backend() == "numpy"
+    with pytest.raises(RuntimeError,
+                       match="ZERROW_KERNEL_BACKEND.*jax not installed"):
+        kd.active_backend()
+    with pytest.raises(RuntimeError, match="ZERROW_KERNEL_BACKEND"):
+        kd.hash_fixed(v)                # every call, not just the first
+    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "numpy")
+    assert kd.active_backend() == "numpy"
     assert_bits(kd.hash_fixed(v), vkernels.hash_fixed(v))
-    # loud once, not per call
-    import warnings as w
-    with w.catch_warnings():
-        w.simplefilter("error")
-        assert kd.active_backend() == "numpy"
+
+
+def test_device_calls_counted_per_kernel_and_platform(pallas_backend):
+    rng = np.random.default_rng(9)
+    v = rng.integers(-50, 50, 300).astype(np.int64)
+    order, starts = vkernels.group_ranges([rng.integers(0, 7, 300)])
+    kd.reset_device_calls()
+    kd.hash_fixed(v)
+    kd.hash_fixed(v)
+    kd.GROUPED_REDUCERS["sum"](v, order, starts)
+    kd.GROUPED_REDUCERS["sum"](v.astype(np.float64), order, starts)
+    plat = rel.platform()
+    assert kd.device_calls() == {("hash_fixed", plat): 2,
+                                 ("grouped_sum", plat): 1}, \
+        "the float sum is registry-ineligible and must not count"
+    kd.reset_device_calls()
+    assert kd.device_calls() == {}
+
+
+def test_process_executor_refuses_pallas_backend(tmp_path, monkeypatch):
+    """One process owns the chip: Flight workers never run the device
+    kernels, so the process executor refuses the backend outright."""
+    from repro.core import ProcessWorkerExecutor
+    store = BufferStore(backing="file", data_dir=str(tmp_path / "store"))
+    try:
+        rm = ResourceManager(store, RMConfig())
+        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+        with pytest.raises(ValueError, match="ZERROW_KERNEL_BACKEND"):
+            ProcessWorkerExecutor(store, rm, workers=1)
+        # flipping the knob after construction is refused before any
+        # worker is spawned
+        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "numpy")
+        ex = ProcessWorkerExecutor(store, rm, workers=1)
+        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+        with pytest.raises(ValueError, match="thread executor"):
+            ex._ensure_pool()
+        assert ex._pool is None
+        ex.close()
+    finally:
+        store.close()
 
 
 def test_unknown_backend_name_raises(monkeypatch):
@@ -426,7 +453,7 @@ def test_pallas_kernel_edit_invalidates_join_cone(monkeypatch):
     fp1 = code_fingerprint(ops.join)
     gb1 = code_fingerprint(ops.group_by)
 
-    def other_hash_fixed(values, *, interpret=None):
+    def other_hash_fixed(values):
         return vkernels.hash_fixed(values)
 
     monkeypatch.setattr(rel, "hash_fixed", other_hash_fixed)
