@@ -48,7 +48,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 import numpy as np  # noqa: E402
 
 from repro.core import (BufferStore, Executor, RMConfig,  # noqa: E402
-                        ResourceManager, kdispatch, zarquet)
+                        ResourceManager, kdispatch, obs, zarquet)
 from repro.core.arrow import Column, Table  # noqa: E402
 from repro.core.plan import compile_plans, scan  # noqa: E402
 
@@ -67,28 +67,11 @@ PATH_KERNELS = ("hash_fixed", "combine_hashes", "filter_join_gather",
 AGGS = {"total": ("amount", "sum"), "lo": ("amount", "min"),
         "hi": ("amount", "max"), "n": ("amount", "count")}
 
-#: jax's own compile spans: tracing, lowering, and the backend compile
-#: (which includes a persistent-cache lookup, so a warm cache shrinks it)
-_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                   "/jax/core/compile/backend_compile_duration")
 
-
-class CompileClock:
-    """Seconds jax spends compiling, summed from its monitoring events."""
-
-    def __init__(self):
-        import jax
-        self._spans = []
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event in _COMPILE_EVENTS:
-            self._spans.append(duration)      # list.append is atomic
-
-    @property
-    def seconds(self) -> float:
-        return float(sum(self._spans))
+def compile_s() -> float:
+    """Seconds jax has spent compiling since the device kernels loaded
+    (``core.obs``'s ``jax.compile_s``)."""
+    return obs.totals("jax.").get("jax.compile_s", 0.0)
 
 
 def check_device():
@@ -188,8 +171,7 @@ def compare(numpy_arm: dict, pallas_arm: dict, n_orders: int) -> None:
 
 def run_star(workdir: str, seed: int, platform: str,
              orders_log2: int = ORDERS_LOG2,
-             customers_log2: int = CUSTOMERS_LOG2,
-             clock: CompileClock = None) -> dict:
+             customers_log2: int = CUSTOMERS_LOG2) -> dict:
     """Phases 3-5; returns the report.  ``platform`` is where the device
     kernels must have served every path kernel."""
     orders_path, customers_path = write_star(workdir, seed, orders_log2,
@@ -198,12 +180,11 @@ def run_star(workdir: str, seed: int, platform: str,
     arms = {}
     for backend in ("numpy", "pallas"):
         kdispatch.reset_device_calls()
-        c0 = clock.seconds if clock else 0.0
+        c0 = compile_s()
         arms[backend] = run_arm(backend, orders_path, customers_path,
                                 workdir)
         report[f"{backend}_wall_s"] = arms[backend]["wall_s"]
-        report[f"{backend}_compile_s"] = (clock.seconds - c0) if clock \
-            else None
+        report[f"{backend}_compile_s"] = compile_s() - c0
     calls = kdispatch.device_calls()
     report["device_calls"] = {k: calls.get((k, platform), 0)
                               for k in PATH_KERNELS}
@@ -227,7 +208,6 @@ def main(argv=None) -> None:
     n_dev = len(jax.devices())
     print(f"device: {dev.platform} / {dev.device_kind} / {n_dev} device(s)")
     print(f"compile cache: {cache_dir}")
-    clock = CompileClock()
 
     os.environ[_BACKEND_ENV] = "pallas"
     try:
@@ -237,11 +217,11 @@ def main(argv=None) -> None:
     print(f"self_check: {sum(v == 'ok' for v in checked.values())} "
           f"kernels bit-identical on the chip, "
           f"{sum(v.startswith('ineligible') for v in checked.values())} "
-          f"documented ineligible; compile {clock.seconds:.3f} s")
+          f"documented ineligible; compile {compile_s():.3f} s")
 
     workdir = tempfile.mkdtemp(prefix="zerrow-chip-smoke-")
     try:
-        report = run_star(workdir, args.seed, dev.platform, clock=clock)
+        report = run_star(workdir, args.seed, dev.platform)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     print(f"star join + group_by (2^{ORDERS_LOG2} orders, "
@@ -254,7 +234,7 @@ def main(argv=None) -> None:
               f"{b} wall {report[f'{b}_wall_s']:.3f} s (compile "
               f"{report[f'{b}_compile_s']:.3f} s)"
               for b in ("numpy", "pallas")))
-    print(f"compile total: {clock.seconds:.3f} s")
+    print(f"compile total: {compile_s():.3f} s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}))

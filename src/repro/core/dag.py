@@ -22,6 +22,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from . import obs
 from .arrow import Table
 from .buffers import AnonRegion, BufferStore, Cgroup
 from .deanon import KernelZero
@@ -240,24 +241,28 @@ class Sandbox:
         store-mutating phases — input reads, output write, and any
         LazyBuf fault the user code triggers — run *inside* the lock
         while the user function itself runs outside it, so vectorized
-        (GIL-releasing) compute overlaps across executor workers."""
+        (GIL-releasing) compute overlaps across executor workers.  The
+        input reads are the span ``sipc.read``."""
         reader = SipcReader(self.store, self.mode, record_map=self.input_map,
                             fault_lock=lock)
         if lock is None:
-            tables = [reader.read_table(m) for m in inputs]
+            with obs.span("sipc.read"):
+                tables = [reader.read_table(m) for m in inputs]
             out_table = fn(tables)
             return self.write_output(out_table, label)
-        with lock:
+        with lock, obs.span("sipc.read"):
             tables = [reader.read_table(m) for m in inputs]
         out_table = fn(tables)
         with lock:
             return self.write_output(out_table, label)
 
     def write_output(self, table: Table, label: str = "") -> SipcMessage:
+        """SIPC-write the node's output table (the span ``sipc.write``)."""
         writer = SipcWriter(self.store, self.kz, self.cgroup, self.mode,
                             input_map=self.input_map,
                             label=label or self.name)
-        msg = writer.write_table(table)
+        with obs.span("sipc.write"):
+            msg = writer.write_table(table)
         # anon regions whose memory was transferred are now file-owned;
         # release the remainder (the wrapper is the last code that runs and
         # never frees data already sent via SIPC — §4.2.3)

@@ -13,8 +13,9 @@ Unix-domain socket:
     {"op": "ping"} / {"op": "shutdown"}
 
 and gets back ``{"ok": True, "msg": <SIPC wire frame>}`` (for
-``exec_chain``: ``{"ok": True, "chain": [{"i", "msg"}, ...]}`` — one
-entry per *echoed* step).  Inputs and
+``exec_chain``: ``{"ok": True, "chain": [{"i", "msg"}, ...],
+"step_s": [...]}`` — one ``chain`` entry per *echoed* step, and the
+seconds each step took on the worker's clock, in step order).  Inputs and
 outputs are *references only* — the worker maps the parent's store files,
 runs the op inside a normal Sandbox (same share wrapper, same SIPC
 writer, so resharing and dictionary sharing work unchanged), writes its
@@ -244,8 +245,9 @@ def _handle(req, store, kz, Sandbox, zarquet) -> Dict[str, Any]:
         # each exec step picks its inputs by index (``args``), which is
         # how fan-in segments (two loads feeding a join) wire up
         vals = [reader.read_table(m) for m in inputs]
-        chain, msgs = [], []
+        chain, msgs, step_s = [], [], []
         for i, step in enumerate(req["steps"]):
+            t0 = time.perf_counter()
             faultplane.fire("worker_chain_kill")
             if step["kind"] == "load":
                 table = zarquet.read_table(
@@ -266,12 +268,13 @@ def _handle(req, store, kz, Sandbox, zarquet) -> Dict[str, Any]:
                 msgs.append(msg)
                 chain.append({"i": i, "msg": encode_message(msg, store)})
             vals.append(table)
+            step_s.append(time.perf_counter() - t0)
         for m in inputs:
             m.release()
         for m in msgs:
             m.release()
         after = store.stats.snapshot()
-        return {"ok": True, "chain": chain,
+        return {"ok": True, "chain": chain, "step_s": step_s,
                 "stats": {k: after[k] - before[k] for k in _ECHO_STATS}}
     if req["op"] == "exec":
         inputs = [decode_message(b, store, charge=False, label=label)

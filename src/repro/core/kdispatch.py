@@ -24,8 +24,9 @@ kernels cannot be imported raises, naming this knob and the cause; an
 unknown backend name raises; ``self_check()`` re-runs a compact
 differential in-process and raises on any kernel whose bits diverge.
 Every call the device kernels serve is counted per kernel and device
-platform (``device_calls()``), which is how a run proves its hot path
-ran on the chip.
+platform (``device_calls()``, kept in ``core.obs``'s counter registry),
+which is how a run proves its hot path ran on the chip; each call is
+also a ``kernel.<name>`` span.
 
 Fingerprint integration: ``fp_includes_join`` / ``fp_includes_group_by``
 are *callables* assigned to the relational ops' ``__fp_includes__``
@@ -40,16 +41,14 @@ engine.
 
 from __future__ import annotations
 
-import collections
 import functools
 import os
-import threading
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import vkernels
+from . import obs, vkernels
 
 __all__ = [
     "BACKENDS", "REGISTRY", "Eligibility", "requested_backend",
@@ -111,30 +110,33 @@ def active_backend() -> str:
     return b
 
 
-#: calls served by the device kernels, keyed (kernel, device platform)
-_device_calls: collections.Counter = collections.Counter()
-_device_calls_lock = threading.Lock()
+#: counter prefix of the calls served by the device kernels, one counter
+#: per kernel and device platform: ``kernel.calls.<kernel>.<platform>``
+_CALLS = "kernel.calls."
 
 
 def device_calls() -> Dict[Tuple[str, str], int]:
     """Calls the device kernels served since the last reset, keyed
     ``(kernel, platform)`` — ``("hash_fixed", "tpu")`` counts hashes
     that ran on the chip."""
-    with _device_calls_lock:
-        return dict(_device_calls)
+    out = {}
+    for name, n in obs.totals(_CALLS).items():
+        kernel, platform = name[len(_CALLS):].rsplit(".", 1)
+        out[(kernel, platform)] = n
+    return out
 
 
 def reset_device_calls() -> None:
-    with _device_calls_lock:
-        _device_calls.clear()
+    """Clear the kernel-dispatch counters: the calls and the bytes
+    copied to and from the device (``kernel.*`` in ``core.obs``)."""
+    obs.reset("kernel.")
 
 
 def _on_device(kernel: str, *args):
     """Serve one call from the device kernel ``kernel`` and count it."""
-    out = getattr(_pallas_mod, kernel)(*args)
-    key = (kernel, _pallas_mod.platform())
-    with _device_calls_lock:
-        _device_calls[key] += 1
+    with obs.span(f"kernel.{kernel}"):
+        out = getattr(_pallas_mod, kernel)(*args)
+    obs.add(f"{_CALLS}{kernel}.{_pallas_mod.platform()}")
     return out
 
 

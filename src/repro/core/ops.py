@@ -60,7 +60,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from . import kdispatch as kd
-from . import vkernels
+from . import obs, vkernels
 from .arrow import (Column, Field, RecordBatch, Schema, Table, UTF8,
                     pack_validity, type_for_np)
 
@@ -298,40 +298,47 @@ def _key_pairs_equal(lcol: Column, li: np.ndarray,
 def _join_gather_indices(lb: RecordBatch, rb: RecordBatch,
                          keys: Sequence[str], how: str,
                          lmask: Optional[np.ndarray] = None,
-                         rmask: Optional[np.ndarray] = None
+                         rmask: Optional[np.ndarray] = None,
+                         span: str = "ops.join"
                          ) -> Tuple[np.ndarray, np.ndarray]:
     """(left, right) original-domain gather index arrays for the join
     output rows (``-1`` right index = left-join miss).  ``lmask`` /
     ``rmask`` restrict each side to mask-true rows — the fused
     filter->join path: the selection composes into the probe/build
     subsets (via ``vkernels.filter_join_gather``), so the caller gathers
-    payload columns exactly once from the *unfiltered* batches."""
-    cast = _key_cast_map(lb, rb, keys)
-    lh, lvalid = _key_hashes(lb, keys, cast)
-    rh, rvalid = _key_hashes(rb, keys, cast)
+    payload columns exactly once from the *unfiltered* batches.  Each
+    step is a span named ``<span>.<step>``."""
+    with obs.span(f"{span}.hash"):
+        cast = _key_cast_map(lb, rb, keys)
+        lh, lvalid = _key_hashes(lb, keys, cast)
+        rh, rvalid = _key_hashes(rb, keys, cast)
     if lmask is not None:
         lvalid &= lmask
     if rmask is not None:
         rvalid &= rmask
-    # null keys never match: probe/build over the valid-key subsets only
-    pidx = np.nonzero(lvalid)[0]
-    bidx = np.nonzero(rvalid)[0]
-    pi, bi = vkernels.hash_join_probe(rh[bidx], lh[pidx])
-    li = kd.filter_join_gather(pidx, pi)
-    ri = kd.filter_join_gather(bidx, bi)
-    keep = np.ones(len(li), dtype=bool)
-    for k in keys:
-        keep &= _key_pairs_equal(lb.column(k), li, rb.column(k), ri)
-    li, ri = li[keep], ri[keep]
-    if how == "left":
-        matched = np.zeros(lb.num_rows, dtype=bool)
-        matched[li] = True
-        cand = ~matched if lmask is None else lmask & ~matched
-        miss = np.nonzero(cand)[0]
-        li = np.concatenate([li, miss])
-        ri = np.concatenate([ri, np.full(len(miss), -1, dtype=np.int64)])
-        order = np.argsort(li, kind="stable")   # restore left-major order
-        li, ri = li[order], ri[order]
+    with obs.span(f"{span}.probe"):
+        # null keys never match: probe/build over the valid-key subsets
+        pidx = np.nonzero(lvalid)[0]
+        bidx = np.nonzero(rvalid)[0]
+        pi, bi = vkernels.hash_join_probe(rh[bidx], lh[pidx])
+    with obs.span(f"{span}.gather_idx"):
+        li = kd.filter_join_gather(pidx, pi)
+        ri = kd.filter_join_gather(bidx, bi)
+    with obs.span(f"{span}.confirm"):
+        keep = np.ones(len(li), dtype=bool)
+        for k in keys:
+            keep &= _key_pairs_equal(lb.column(k), li, rb.column(k), ri)
+        li, ri = li[keep], ri[keep]
+        if how == "left":
+            matched = np.zeros(lb.num_rows, dtype=bool)
+            matched[li] = True
+            cand = ~matched if lmask is None else lmask & ~matched
+            miss = np.nonzero(cand)[0]
+            li = np.concatenate([li, miss])
+            ri = np.concatenate([ri, np.full(len(miss), -1,
+                                             dtype=np.int64)])
+            order = np.argsort(li, kind="stable")   # left-major order
+            li, ri = li[order], ri[order]
     return li, ri
 
 
@@ -384,10 +391,12 @@ def join(left: Table, right: Table, on: Union[str, Sequence[str]],
     """
     assert how in ("inner", "left"), how
     keys = [on] if isinstance(on, str) else list(on)
-    lb = left.combine().batches[0]
-    rb = right.combine().batches[0]
-    li, ri = _join_gather_indices(lb, rb, keys, how)
-    return _join_output(lb, rb, keys, li, ri, suffix)
+    with obs.span("ops.join", rows=left.num_rows):
+        lb = left.combine().batches[0]
+        rb = right.combine().batches[0]
+        li, ri = _join_gather_indices(lb, rb, keys, how)
+        with obs.span("ops.join.output"):
+            return _join_output(lb, rb, keys, li, ri, suffix)
 
 
 #: a mask for one side of a fused filter->join: a bool/int array over the
@@ -424,12 +433,17 @@ def filter_join(left: Table, right: Table, on: Union[str, Sequence[str]],
     Flight process boundary)."""
     assert how in ("inner", "left"), how
     keys = [on] if isinstance(on, str) else list(on)
-    lb = left.combine().batches[0]
-    rb = right.combine().batches[0]
-    lm = None if left_mask is None else _resolve_mask(left_mask, lb)
-    rm = None if right_mask is None else _resolve_mask(right_mask, rb)
-    li, ri = _join_gather_indices(lb, rb, keys, how, lmask=lm, rmask=rm)
-    return _join_output(lb, rb, keys, li, ri, suffix)
+    with obs.span("ops.filter_join", rows=left.num_rows):
+        lb = left.combine().batches[0]
+        rb = right.combine().batches[0]
+        with obs.span("ops.filter_join.mask"):
+            lm = None if left_mask is None else _resolve_mask(left_mask, lb)
+            rm = None if right_mask is None else _resolve_mask(right_mask,
+                                                               rb)
+        li, ri = _join_gather_indices(lb, rb, keys, how, lmask=lm, rmask=rm,
+                                      span="ops.filter_join")
+        with obs.span("ops.filter_join.output"):
+            return _join_output(lb, rb, keys, li, ri, suffix)
 
 
 def _group_codes(col: Column) -> np.ndarray:
@@ -487,35 +501,40 @@ def group_by(table: Table, keys: Union[str, Sequence[str]],
     if clash:
         raise ValueError(f"agg output name(s) {clash} collide with key "
                          f"column(s); pick a different out_name")
-    b = table.combine().batches[0]
-    order, starts = vkernels.group_ranges(
-        [_group_codes(b.column(k)) for k in keys])
-    reps = order[starts]
-    fields: List[Field] = []
-    cols: List[Column] = []
-    for k in keys:
-        c = b.column(k).take(reps)
-        fields.append(Field(k, c.type))
-        cols.append(c)
-    for out_name, (col_name, how) in aggs.items():
-        reducer = kd.GROUPED_REDUCERS[how]
-        c = b.column(col_name)
-        if how == "count":
-            v = np.empty(c.length, dtype=np.int64)    # values unused
-        else:
-            assert c._kindof() == "prim", \
-                f"{how}({col_name}): non-numeric column"
-            v = c._logical()
-        valid = None if c.validity is None else c.valid_mask()
-        vals, counts = reducer(v, order, starts, valid)
-        if how in ("min", "max") and v.dtype == np.bool_:
-            vals = vals.astype(bool)
-        validity = None
-        if how != "count" and (counts == 0).any():
-            validity = pack_validity(counts > 0)      # all-null group
-        fields.append(Field(out_name, type_for_np(vals.dtype)))
-        cols.append(Column.primitive(vals, validity=validity))
-    return Table.from_batch(Schema(fields), cols)
+    with obs.span("ops.group_by", rows=table.num_rows):
+        b = table.combine().batches[0]
+        with obs.span("ops.group_by.codes"):
+            codes = [_group_codes(b.column(k)) for k in keys]
+        with obs.span("ops.group_by.ranges"):
+            order, starts = vkernels.group_ranges(codes)
+        fields: List[Field] = []
+        cols: List[Column] = []
+        with obs.span("ops.group_by.keys"):
+            reps = order[starts]
+            for k in keys:
+                c = b.column(k).take(reps)
+                fields.append(Field(k, c.type))
+                cols.append(c)
+        with obs.span("ops.group_by.reduce"):
+            for out_name, (col_name, how) in aggs.items():
+                reducer = kd.GROUPED_REDUCERS[how]
+                c = b.column(col_name)
+                if how == "count":
+                    v = np.empty(c.length, dtype=np.int64)  # values unused
+                else:
+                    assert c._kindof() == "prim", \
+                        f"{how}({col_name}): non-numeric column"
+                    v = c._logical()
+                valid = None if c.validity is None else c.valid_mask()
+                vals, counts = reducer(v, order, starts, valid)
+                if how in ("min", "max") and v.dtype == np.bool_:
+                    vals = vals.astype(bool)
+                validity = None
+                if how != "count" and (counts == 0).any():
+                    validity = pack_validity(counts > 0)  # all-null group
+                fields.append(Field(out_name, type_for_np(vals.dtype)))
+                cols.append(Column.primitive(vals, validity=validity))
+        return Table.from_batch(Schema(fields), cols)
 
 
 def join_node(tables: Sequence[Table], on, how: str = "inner",

@@ -48,7 +48,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from ..dag import (CACHED, COMPLETE, DAG, DONE, EVICTED, NodeState, RUNNING,
                    Sandbox, WAITING)
 from ..fingerprint import fingerprint_dag
-from .. import kdispatch, zarquet
+from .. import kdispatch, obs, zarquet
 
 #: sentinel: nothing runnable now, but in-flight nodes may unblock us
 _WAIT = object()
@@ -558,11 +558,12 @@ class WorkerPoolExecutor:
             with lock:
                 sb.register_anon(a)
 
-        table = zarquet.read_table(
-            st.spec.source, dict_columns=st.spec.dict_columns,
-            columns=st.spec.columns, row_groups=st.spec.row_groups,
-            on_buffer=on_buffer,
-            reader_threads=getattr(self.rm.cfg, "reader_threads", None))
+        with obs.span("loader.decode"):
+            table = zarquet.read_table(
+                st.spec.source, dict_columns=st.spec.dict_columns,
+                columns=st.spec.columns, row_groups=st.spec.row_groups,
+                on_buffer=on_buffer,
+                reader_threads=getattr(self.rm.cfg, "reader_threads", None))
         with self._lock:
             return sb.write_output(table, label=st.name)
 
@@ -910,7 +911,6 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
         chain = self._chain_claims.pop((st.dag.id, st.name), None)
         if chain is None:
             return super()._execute(st)
-        t0 = time.perf_counter()
         try:
             self._execute_chain(chain)
         except BaseException as e:
@@ -929,9 +929,6 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
                         n.transition(WAITING)
                 self._cond.notify_all()
             raise
-        dt = (time.perf_counter() - t0) / len(chain)
-        for n in chain:
-            n.exec_latency = dt
 
     def _chain_echo(self, n: NodeState, is_tail: bool) -> bool:
         """Should this chain step's output cross the wire back?  Tails
@@ -972,8 +969,14 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
         (sandboxes, input export, output adoption, DeCache insert) stays
         under the RM critical section; frame encode/decode and the
         socket round-trip run outside it.  Suffix nodes complete here;
-        the head completes through the caller's normal path."""
+        the head completes through the caller's normal path.
+
+        Each node's ``exec_latency`` is its step's time on the worker's
+        clock plus an even share of what the steps leave of the chain's
+        wall time (the shared encode, socket round trip and adoption); a
+        head answered from the DeCache has no step, only the share."""
         from ..flight import wire
+        t0 = time.perf_counter()
         head = chain[0]
         key = None
         shipped = chain
@@ -1050,6 +1053,11 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
                     self._attach[head.dag.id].append(e)
             self.chains_shipped += 1
             self.chain_nodes_shipped += len(shipped)
+            step_s = dict(zip((n.name for n in shipped), reply["step_s"]))
+            shared = max(time.perf_counter() - t0
+                         - sum(step_s.values()), 0.0) / len(chain)
+            for n in chain:
+                n.exec_latency = step_s.get(n.name, 0.0) + shared
         finally:
             if key is not None:
                 with self._cond:

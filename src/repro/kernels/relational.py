@@ -28,7 +28,11 @@ Each kernel has two parts:
     ``core/vkernels.py``): it views the inputs' raw bits, pads them to a
     bucketed length (``_bucket``) so a new row count rarely means a new
     compile, runs the jitted core under scoped 64-bit mode, and returns
-    numpy arrays.
+    numpy arrays.  The call (``_call``) is three blocked steps, each a
+    span (``core.obs``): ``kernel.put`` copies the padded arguments to
+    the device, ``kernel.run`` runs the program, ``kernel.fetch`` copies
+    the outputs back; the bytes that cross are counted in
+    ``kernel.h2d_bytes`` and ``kernel.d2h_bytes``.
 
 Ported kernels:
 
@@ -67,7 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import enable_x64
-from repro.core import vkernels
+from repro.core import obs, vkernels
 
 __all__ = [
     "hash_fixed", "combine_hashes", "hash_keys",
@@ -80,6 +84,8 @@ _GOLDEN = np.uint64(0x9E3779B97F4A7C15)
 
 #: smallest padded length: tiny inputs share one compiled shape
 _MIN_ROWS = 1024
+
+obs.watch_compiles(jax.monitoring)
 
 
 def platform() -> str:
@@ -109,12 +115,27 @@ def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
     return out
 
 
+def _nbytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
 def _call(core, args, static):
     """``jax.jit(core)(*args, **static)`` on the default device under
-    64-bit mode, outputs copied back as numpy arrays."""
+    64-bit mode, outputs copied back as numpy arrays.  The copy in, the
+    run and the copy out are each waited for, so each span holds its own
+    step's time."""
     with enable_x64():
-        out = jax.jit(core, static_argnames=tuple(static))(*args, **static)
-        return jax.tree.map(np.asarray, out)
+        obs.add("kernel.h2d_bytes", _nbytes(args))
+        with obs.span("kernel.put"):
+            args = jax.block_until_ready(jax.device_put(args))
+        with obs.span("kernel.run"):
+            out = jax.block_until_ready(
+                jax.jit(core, static_argnames=tuple(static))(*args,
+                                                             **static))
+        with obs.span("kernel.fetch"):
+            out = jax.tree.map(np.asarray, out)
+        obs.add("kernel.d2h_bytes", _nbytes(out))
+        return out
 
 
 def _bits(values: np.ndarray) -> Tuple[np.ndarray, int]:
