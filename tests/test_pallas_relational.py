@@ -249,17 +249,72 @@ def _values(rng, n, dtype):
     return rng.integers(-100, 100, n).astype(dtype)
 
 
+def _near_top(rng, n, dtype):
+    """Values a little inside the dtype's reach: ±2**62 for int64, 2**63
+    for uint64, ±2**31 for int32 (two of them fit every accumulator)."""
+    if dtype == "bool":
+        return rng.random(n) < 0.5
+    off = rng.integers(0, 1000, n)
+    if dtype == "uint64":
+        return np.uint64(1 << 63) - off.astype(np.uint64)
+    top = (1 << 62) if dtype == "int64" else (1 << 31) - 1
+    return ((top - off) * rng.choice([-1, 1], n)).astype(dtype)
+
+
+def _reducer_case(rng, shape, dtype):
+    """(codes, values) for a group shape:
+
+    * ``spread`` / ``dup_heavy`` / ``empty``: random codes over 200 / 3
+      keys, or no rows;
+    * ``prefix_overflow``: two rows a group, each near the dtype's top,
+      so the running prefix over the sorted rows wraps many times while
+      no group's own total does;
+    * ``group_wraps``: small values, but one group's rows all near the
+      top, so that group's 64-bit total wraps;
+    * ``bucket_groups``: exactly ``_bucket(1024)`` groups, so the device
+      edge pads no group and the last group ends at the last row;
+    * ``one_group`` / ``singletons``: all rows in one group; one row a
+      group;
+    * ``null_groups``: random codes; the caller nulls every row of some
+      groups."""
+    n = 0 if shape == "empty" else 1500
+    if shape == "prefix_overflow":
+        return rng.permutation(n) // 2, _near_top(rng, n, dtype)
+    v = _values(rng, n, dtype)
+    if shape == "group_wraps":
+        codes = rng.integers(0, 200, n)
+        top = _near_top(rng, n, dtype)
+        hot = codes == codes[0]
+        v[hot] = np.abs(top[hot]) if dtype != "bool" else True
+        return codes, v
+    if shape == "bucket_groups":
+        g = rel._bucket(1024)
+        assert g == 1024
+        return np.concatenate([np.arange(g), rng.integers(0, g, n - g)]), v
+    if shape == "one_group":
+        return np.zeros(n, np.int64), v
+    if shape == "singletons":
+        return rng.permutation(n), v
+    return rng.integers(0, 3 if shape == "dup_heavy" else 200, n), v
+
+
+_REDUCER_SHAPES = ["spread", "dup_heavy", "empty", "prefix_overflow",
+                   "group_wraps", "bucket_groups", "one_group", "singletons",
+                   "null_groups"]
+
+
 @pytest.mark.parametrize("dtype", ["int32", "int64", "uint64", "bool"])
 @pytest.mark.parametrize("null_frac", [0.0, 0.3, 1.0])
-@pytest.mark.parametrize("shape", ["spread", "dup_heavy", "empty"])
+@pytest.mark.parametrize("shape", _REDUCER_SHAPES)
 def test_grouped_reducers_triple_identical(dtype, null_frac, shape):
     rng = np.random.default_rng(hash((dtype, null_frac, shape)) % 2**32)
-    n = 0 if shape == "empty" else 1500
-    card = 3 if shape == "dup_heavy" else 200
-    codes = rng.integers(0, card, n)
+    codes, v = _reducer_case(rng, shape, dtype)
+    n = len(codes)
     order, starts = vkernels.group_ranges([codes])
-    v = _values(rng, n, dtype)
     valid = None if null_frac == 0.0 else rng.random(n) >= null_frac
+    if shape == "null_groups":
+        valid = (np.ones(n, bool) if valid is None else valid) \
+            & (codes % 5 != 0)
     for op, pf, nf in (("count", rel.grouped_count, vkernels.grouped_count),
                        ("sum", rel.grouped_sum, vkernels.grouped_sum),
                        ("min", rel.grouped_min, vkernels.grouped_min),

@@ -109,6 +109,25 @@ def test_segment_reducer_without_nulls_compiles_for_v5e(one_chip):
             op="sum").compile()
 
 
+@pytest.mark.parametrize("with_valid", [False, True])
+@pytest.mark.parametrize("op", ["count", "sum", "min", "max"])
+def test_segment_reducer_lowers_without_loop_or_scatter(one_chip, op,
+                                                        with_valid):
+    """Groups are contiguous runs, so no reducer searches for segment
+    ids (a ``while``), and sum and count are prefix differences with no
+    scatter; min/max keep one, of the group-head flags."""
+    s = one_chip
+    with enable_x64():
+        hlo = jax.jit(rel._segreduce_core, static_argnames=("op",)).lower(
+            None if op == "count" else _spec((ROWS,), jnp.int64, s),
+            _spec((ROWS,), jnp.int64, s), _spec((GROUPS,), jnp.int64, s),
+            _spec((ROWS,), jnp.bool_, s) if with_valid else None,
+            _spec((), jnp.int64, s), op=op).as_text()
+    assert "stablehlo.while" not in hlo
+    if op in ("count", "sum"):
+        assert "stablehlo.scatter" not in hlo
+
+
 def test_cases_cover_every_registered_device_kernel():
     from repro.core import kdispatch
     eligible = {k.split(":")[0] for k, e in kdispatch.REGISTRY.items()
