@@ -6,7 +6,8 @@
 For each seed, generates the cell's tables at their configured size,
 evaluates every query of the rotation with the plain reference and with
 its control (``reference.run(..., control=True)``: every number in
-float32, sums accumulated in float32), and prints the number a run
+float32, sums accumulated in float32, ``count`` over rows with nulls
+included), and prints the number a run
 compares, ``wrong_rows`` summed over one pass of the rotation, as one
 JSON line per seed.  A control that the comparison passes would make
 the comparison worthless; its smallest reading over the seeds is the

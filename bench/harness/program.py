@@ -74,7 +74,7 @@ def build_plan(spec: dict, paths: Dict[str, str],
             plan = plan.filter(predicate(step["filter"]))
         elif "join" in step:
             plan = plan.join(build_plan(step["join"], paths, dict_columns),
-                             on=step["on"])
+                             on=step["on"], how=step.get("how", "inner"))
         else:
             plan = plan.group_by(step["group_by"],
                                  {k: tuple(v) for k, v in

@@ -4,17 +4,24 @@ A query is JSON data, so that a traffic mix is a data file:
 
     plan      := {"scan": <table>, "steps": [step, ...]}       (steps optional)
     step      := {"filter": pred}
-               | {"join": plan, "on": <column>}                  inner join
-               | {"group_by": [<key>, ...], "aggs": {<out>: [<column>, <how>]}}
+               | {"join": plan, "on": <column>, "how": <join>}   (how optional)
+               | {"group_by": [<key>, ...], "aggs": {<out>: [<column>, <agg>]}}
+    join      := "inner" (the default) | "left"          (left outer join)
+    agg       := "sum" | "min" | "max" | "count"
     pred      := ["and", pred, pred, ...] | ["or", pred, pred, ...]
                | [<op>, <column>, <literal>]      op: == != < <= > >=
                | ["in", <column>, [<literal>, ...]]   (an OR of equalities)
                | ["between", <column>, <lo>, <hi>]    (inclusive)
 
 A join's right side is a plan of its own (a filtered dimension, or a
-subquery such as Q18's).  Both sides name the key alike.  The program
-(``harness.program``) and the plain reference (``harness.reference``)
-each interpret this tree on their own; this module only walks it.
+subquery such as Q18's).  Both sides name the key alike.  A left join
+keeps a left row that matches nothing once, its right columns null.
+Aggregates follow SQL: nulls are left out of every one, so ``count`` is
+``count(column)``, the column's non-null values (its row count where it
+has no nulls), and a ``sum``, ``min`` or ``max`` over no value is null.
+The program (``harness.program``) and the plain reference
+(``harness.reference``) each interpret this tree on their own; this
+module only walks it.
 """
 
 from __future__ import annotations
@@ -23,6 +30,7 @@ from typing import Dict, Iterator, List
 
 COMPARISONS = ("==", "!=", "<", "<=", ">", ">=")
 AGGREGATES = ("sum", "min", "max", "count")
+JOINS = ("inner", "left")
 
 
 def scanned_tables(plan: dict) -> List[str]:
@@ -63,12 +71,14 @@ def validate(plan: dict) -> None:
     if set(plan) - {"scan", "steps"} or "scan" not in plan:
         raise ValueError(f"plan keys {sorted(plan)}: want scan, steps")
     for step in plan.get("steps", ()):
-        kind = set(step) - {"on", "aggs"}
+        kind = set(step) - {"on", "how", "aggs"}
         if kind == {"filter"}:
             validate_predicate(step["filter"])
         elif kind == {"join"}:
             if not isinstance(step.get("on"), str):
                 raise ValueError(f"join needs one key column: {step}")
+            if step.get("how", "inner") not in JOINS:
+                raise ValueError(f"join how {step['how']!r} not in {JOINS}")
             validate(step["join"])
         elif kind == {"group_by"}:
             for how in (a[1] for a in step["aggs"].values()):

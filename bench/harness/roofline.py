@@ -9,8 +9,9 @@ algorithm, so a share can only exceed 100% where the device time leaves
 out part of the work.
 
 * A hash join reads each side's key column once and writes one (left,
-  right) pair of int64 row indices per match:
-  ``(left_rows + right_rows) * key_bytes + matches * 16``.
+  right) pair of int64 row indices per match, and a left join one
+  (left, -1) pair per left row that matches nothing:
+  ``(left_rows + right_rows) * key_bytes + (matches + misses) * 16``.
 * A group-by aggregate reads each row's value and its int64 group
   position and writes one int64 accumulator per group:
   ``rows * (value_bytes + 8) + groups * 8``.
@@ -29,7 +30,7 @@ REDUCE_PROGRAMS = ("_segreduce_core",)
 
 def join_bytes(op: dict) -> int:
     return (op["left_rows"] + op["right_rows"]) * op["key_bytes"] \
-        + op["matches"] * 16
+        + (op["matches"] + op.get("misses", 0)) * 16
 
 
 def group_by_bytes(op: dict) -> int:

@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from harness import cellrun, queryspec, spec
+from harness import cellrun, queryspec, roofline, spec
 
 BENCH = json.load(open(os.path.join(spec.ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -98,3 +98,14 @@ def test_readers_on_a_record():
         100 * (100 * 16 + 40) / 1e9 / 0.02)
     assert read["data_plane.copied_bytes_per_row"] == 3.0
     assert read["loaders.load_s_per_query"] == 0.25
+
+
+@pytest.mark.parametrize("misses", [0, 7])
+def test_join_bytes_count_misses(misses):
+    """A left join writes one (left, -1) index pair per miss; an entry
+    without ``misses`` (an inner join) needs the bytes it always did."""
+    op = {"op": "join", "left_rows": 1000, "right_rows": 10,
+          "key_bytes": 4, "matches": 100}
+    base = roofline.join_bytes(op)
+    assert base == 1010 * 4 + 100 * 16
+    assert roofline.join_bytes(dict(op, misses=misses)) == base + misses * 16
