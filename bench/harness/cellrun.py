@@ -14,11 +14,15 @@ completed inside the window over the time from the window's start to
 the last of those completions; the query in flight at the deadline
 finishes, and is compared, but does not count.  With ``trace`` the
 window runs under the profiler and the per-layer metrics are read
-instead.
+instead, from the reduced trace, the program's spans in it
+(``harness.spans``), its counters and the reference's cardinalities.
 
 After the window: the memory peak is read, the device-call and
-compile counts are checked, and every query's result is compared, row
-for row, with the plain reference's, computed once per query.
+compile counts are checked (every path kernel — the joins', each
+group-by aggregate's and those the traffic names under ``"kernels"`` —
+must have served calls on the chip), and every query's result is
+compared, row for row, with the plain reference's, computed once per
+query.
 """
 
 from __future__ import annotations
@@ -30,9 +34,10 @@ import shutil
 import tempfile
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional
 
-from harness import device, program, queryspec, reference, spec, trace
+from harness import (device, program, queryspec, reference, spans, spec,
+                     trace)
 
 #: relational kernels every query's joins run through
 JOIN_KERNELS = ("hash_fixed", "combine_hashes", "filter_join_gather")
@@ -48,6 +53,9 @@ class RunRecord:
     queries: List[dict] = field(default_factory=list)
     trace: Optional[dict] = None
     peaks: Optional[dict] = None
+    #: self seconds per program span in the window, as ``spans.reduce``
+    #: gives them; None untraced, empty for a program without spans
+    span_self_s: Optional[Dict[str, float]] = None
 
 
 def _annotate(on: bool, name: str):
@@ -104,6 +112,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     queries = mix["queries"]
     for q in queries:
         queryspec.validate(q["plan"])
+    named_kernels = spec.path_kernels(mix)
 
     devices = require_chip(cell["chips"])
     platform = devices[0].platform
@@ -149,10 +158,12 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         window_compiles = clock.compiles - warm_compiles
         calls = kdispatch.device_calls()
         memory_peak = device.memory_peak_bytes(devices)
-        compact = None
+        compact = span_self_s = None
         if traced:
             path = _trace_file(logdir)
             compact = trace.extract(path)
+            span_self_s = spans.self_seconds(spans.extract(path),
+                                             *compact["window"])
             if trace_out:
                 shutil.copy(path, trace_out + ".xplane.pb")
                 with open(trace_out + ".json", "w") as fh:
@@ -160,8 +171,9 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
 
-    needed = set(JOIN_KERNELS) | {f"grouped_{a}" for q in queries
-                                  for a in queryspec.aggregates_used(q["plan"])}
+    needed = set(JOIN_KERNELS) | set(named_kernels) | {
+        f"grouped_{a}" for q in queries
+        for a in queryspec.aggregates_used(q["plan"])}
     idle = sorted(k for k in needed if calls.get((k, platform), 0) == 0)
     if idle:
         raise RunFailed(f"path kernels served no {platform} call in the "
@@ -207,7 +219,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         reduced = trace.reduce(compact)
         if reduced["busy_s"] <= 0:
             raise RunFailed("no device operation in the traced window")
-        record = RunRecord(peaks=peaks, trace=reduced, queries=[
+        record = RunRecord(peaks=peaks, trace=reduced,
+                           span_self_s=span_self_s, queries=[
             {"name": q["name"], "load_s": r.load_s,
              "bytes_copied": r.bytes_copied, "ops": want[q["name"]][1],
              "rows_scanned": queryspec.rows_scanned(q["plan"], table_rows)}

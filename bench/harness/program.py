@@ -52,11 +52,18 @@ def write_tables(tables: Dict[str, Dict[str, np.ndarray]],
 
 
 def predicate(pred: list):
+    """A traffic predicate as a frontend ``Expr``.  ``like`` becomes
+    ``col(c).like(pattern)``, a method the program does not have yet:
+    a plan that uses it fails while it is built."""
     op = pred[0]
     if op in ("and", "or"):
         parts = [predicate(p) for p in pred[1:]]
         return reduce(operator.and_ if op == "and" else operator.or_, parts)
     c = col(pred[1])
+    if op == "like":
+        return c.like(pred[2])
+    if op == "not like":
+        return ~c.like(pred[2])
     if op == "in":
         return reduce(operator.or_, [c == v for v in pred[2]])
     if op == "between":

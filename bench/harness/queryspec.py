@@ -12,6 +12,8 @@ A query is JSON data, so that a traffic mix is a data file:
                | [<op>, <column>, <literal>]      op: == != < <= > >=
                | ["in", <column>, [<literal>, ...]]   (an OR of equalities)
                | ["between", <column>, <lo>, <hi>]    (inclusive)
+               | ["like", <column>, <pattern>]      (SQL LIKE, % only)
+               | ["not like", <column>, <pattern>]
 
 A join's right side is a plan of its own (a filtered dimension, or a
 subquery such as Q18's).  Both sides name the key alike.  A left join
@@ -19,6 +21,12 @@ keeps a left row that matches nothing once, its right columns null.
 Aggregates follow SQL: nulls are left out of every one, so ``count`` is
 ``count(column)``, the column's non-null values (its row count where it
 has no nulls), and a ``sum``, ``min`` or ``max`` over no value is null.
+
+``like`` is SQL LIKE on a utf8 column, case- and byte-exact, with ``%``
+(any run of bytes, the empty one too) as its only wildcard; ``not like``
+is its complement.  ``_`` and ``\\`` escapes are refused: TPC-H's LIKEs
+(Q2, Q9, Q13, Q14, Q16, Q20) use ``%`` alone.
+
 The program (``harness.program``) and the plain reference
 (``harness.reference``) each interpret this tree on their own; this
 module only walks it.
@@ -29,6 +37,7 @@ from __future__ import annotations
 from typing import Dict, Iterator, List
 
 COMPARISONS = ("==", "!=", "<", "<=", ">", ">=")
+LIKES = ("like", "not like")
 AGGREGATES = ("sum", "min", "max", "count")
 JOINS = ("inner", "left")
 
@@ -102,5 +111,12 @@ def validate_predicate(pred: list) -> None:
     elif op == "between":
         if len(pred) != 4:
             raise ValueError(f"between takes a column, lo and hi: {pred}")
+    elif op in LIKES:
+        if len(pred) != 3 or not isinstance(pred[1], str) \
+                or not isinstance(pred[2], str):
+            raise ValueError(f"{op} takes a column and a pattern: {pred}")
+        if "_" in pred[2] or "\\" in pred[2]:
+            raise ValueError(f"{op} pattern {pred[2]!r}: only % is a "
+                             f"wildcard, and there are no escapes")
     else:
         raise ValueError(f"unknown predicate {op!r}")

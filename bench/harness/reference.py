@@ -3,8 +3,9 @@
 It reads the generated tables (``{table: {column: ndarray}}``, text as
 numpy bytes arrays) and imports nothing of the program: a join sorts the
 right key and binary-searches the left one, a group-by lexsorts its keys
-and reduces each run with ``ufunc.reduceat``.  Integer aggregates
-accumulate in int64, so every result is exact.
+and reduces each run with ``ufunc.reduceat``, a ``like`` matches its
+pattern's pieces with ``np.strings``.  Integer aggregates accumulate in
+int64, so every result is exact.
 
 Nulls follow SQL as far as a left join brings them in.  Each right
 column a left join brings in has a boolean validity array beside it
@@ -16,9 +17,9 @@ where a plan does.
 
 ``run(plan, tables)`` returns the result rows (tuples of Python values,
 text decoded, in no particular order) and the cardinalities of every
-join and group-by, from which ``harness.roofline`` computes the bytes
-those operators need.  ``run(..., control=True)`` is the control of the
-comparison, which must come out as not correct.  It takes the two
+join, group-by and ``like``, from which ``harness.roofline`` computes
+the bytes those operators need.  ``run(..., control=True)`` is the
+control of the comparison, which must come out as not correct.  It takes the two
 shortcuts a later change might be tempted to take:
 
 * every number is cast to float32, the TPU's native 32-bit type, before
@@ -72,13 +73,40 @@ def _literal(v):
     return v.encode() if isinstance(v, str) else v
 
 
-def mask(pred: list, frame: Frame) -> np.ndarray:
+def like(col: np.ndarray, pattern: str) -> np.ndarray:
+    """SQL LIKE on a numpy bytes array, ``%`` the only wildcard.  The
+    pattern's first piece is a prefix and its last a suffix; the middle
+    pieces are found leftmost, in order, after the prefix, and the
+    suffix starts at or after the end of the last of them."""
+    if col.dtype.kind != "S":
+        raise ValueError(f"like on a {col.dtype} column: want text")
+    pieces = [p.encode() for p in pattern.split("%")]
+    if len(pieces) == 1:
+        return col == pieces[0]
+    first, *middle, last = pieces
+    ok = np.strings.startswith(col, first)
+    at = np.full(len(col), len(first), dtype=np.int64)
+    for piece in filter(None, middle):
+        found = np.strings.find(col, piece, at)
+        ok &= found >= 0
+        at = found + len(piece)
+    return ok & np.strings.endswith(col, last) \
+        & (np.strings.str_len(col) - len(last) >= at)
+
+
+def mask(pred: list, frame: Frame, stats: List[dict]) -> np.ndarray:
     op = pred[0]
     if op in ("and", "or"):
-        parts = [mask(p, frame) for p in pred[1:]]
+        parts = [mask(p, frame, stats) for p in pred[1:]]
         return reduce(np.logical_and if op == "and" else np.logical_or,
                       parts)
     col = frame.non_null(pred[1], "a filter")
+    if op in ("like", "not like"):
+        m = like(col, pred[2])
+        stats.append({"op": "like", "rows": len(col),
+                      "value_bytes": int(np.strings.str_len(col).sum()),
+                      "matches": int(m.sum())})
+        return m if op == "like" else ~m
     if op == "in":
         return np.isin(col, [_literal(v) for v in pred[2]])
     if op == "between":
@@ -182,7 +210,7 @@ def evaluate(plan: dict, tables: Dict[str, Dict[str, np.ndarray]],
                        for k, v in frame.items()})
     for step in plan.get("steps", ()):
         if "filter" in step:
-            frame = frame.take(mask(step["filter"], frame))
+            frame = frame.take(mask(step["filter"], frame, stats))
         elif "join" in step:
             right = evaluate(step["join"], tables, control, stats)
             frame = join(frame, right, step["on"],

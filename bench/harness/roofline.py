@@ -15,6 +15,10 @@ out part of the work.
 * A group-by aggregate reads each row's value and its int64 group
   position and writes one int64 accumulator per group:
   ``rows * (value_bytes + 8) + groups * 8``.
+* A ``like`` (or ``not like``) reads the column's text and its int64
+  offsets (``harness.program``'s utf8 layout: ``rows + 1`` of them)
+  and writes one bool a row:
+  ``value_bytes + (rows + 1) * 8 + rows``.
 """
 
 from __future__ import annotations
@@ -36,6 +40,10 @@ def join_bytes(op: dict) -> int:
 def group_by_bytes(op: dict) -> int:
     return sum(op["rows"] * (vb + 8) + op["groups"] * 8
                for vb in op["value_bytes"])
+
+
+def like_bytes(op: dict) -> int:
+    return op["value_bytes"] + (op["rows"] + 1) * 8 + op["rows"]
 
 
 def share(needed_bytes: int, device_s: float, hbm_bytes_per_s: float

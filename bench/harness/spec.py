@@ -3,7 +3,9 @@
 A cell (``workloads`` entry) names a configuration and a traffic mix.
 The configuration's file is the entry's ``file``; its ``generator``
 names ``bench/generators/<generator>.py``.  The traffic mix is
-``bench/traffic/<traffic>.json``.  A per-layer metric is read by
+``bench/traffic/<traffic>.json``; its optional ``"kernels"`` lists the
+device kernels (``kdispatch`` names) its queries must run on the chip
+beyond the joins' and group-bys'.  A per-layer metric is read by
 ``bench/metrics/<metric name>.py``, whose ``read(run)`` returns the
 value or None.  Adding a cell or a metric adds files and entries; no
 code here changes.
@@ -58,6 +60,17 @@ def traffic(cell_: dict) -> dict:
     with open(os.path.join(BENCH, "traffic", f"{cell_['traffic']}.json")) \
             as fh:
         return json.load(fh)
+
+
+def path_kernels(mix: dict) -> List[str]:
+    """The kernels a traffic mix names under ``"kernels"``, checked to
+    be a list of names."""
+    names = mix.get("kernels", [])
+    if not isinstance(names, list) or not all(
+            isinstance(k, str) and NAME.match(k) for k in names):
+        raise ValueError(f"traffic kernels {names!r}: want a list of "
+                         f"kernel names")
+    return names
 
 
 def generator(config_: dict):

@@ -12,7 +12,8 @@ from the harness's log (``traced_rows_per_s``: rows of the queries
 completed inside the window over the time to the last of them) and its
 compile count; ``ops.host_s_per_query`` and
 ``kdispatch.call_s_per_query`` (self time of the ``ops.*`` and of the
-``kernel.*`` spans, per query); ``span_self_s``; ``idle_by_span``; and
+``kernel.*`` spans per query, read by the per-layer readers that give
+them in a traced result line); ``span_self_s``; ``idle_by_span``; and
 the share of the device's idle time that a program span holds.  A
 program without spans gives an empty ``span_self_s``, null span metrics
 and all idle time outside program spans.  With ``--out`` the trace's
@@ -83,7 +84,7 @@ def summarize(result: dict, lines: list, compact: dict,
               found: list) -> dict:
     """The report of one traced run: its result line, its log lines, and
     ``harness.trace.extract`` / ``harness.spans.extract`` of its trace."""
-    from harness import spans, trace
+    from harness import cellrun, spans, spec, trace
     report = {"result": result}
     for line in lines:
         m = _WINDOW.match(line)
@@ -95,10 +96,11 @@ def summarize(result: dict, lines: list, compact: dict,
             report["window_compiles"] = int(m.group(1))
     reduced = spans.reduce(compact, found)
     selfs = reduced["span_self_s"]
-    for name, prefix in (("ops.host_s_per_query", "ops."),
-                         ("kdispatch.call_s_per_query", "kernel.")):
-        report[name] = spans.seconds_per_query(selfs, prefix,
-                                               result["attempted"])
+    # the per-layer readers themselves, over the window's queries
+    record = cellrun.RunRecord(queries=[{}] * result["attempted"],
+                               span_self_s=selfs)
+    for name in ("ops.host_s_per_query", "kdispatch.call_s_per_query"):
+        report[name] = spec.reader(name).read(record)
     report["span_self_s"] = dict(sorted(selfs.items(),
                                         key=lambda kv: -kv[1]))
     report["idle_by_span"] = reduced["idle_by_span"]

@@ -49,6 +49,7 @@ def test_cell_finds_its_files(cell):
     assert all(k in config for k in entry["reduced"])
     assert config["assumed"] and config["source"]
     assert hasattr(spec.generator(config), "generate")
+    spec.path_kernels(spec.traffic(c))
     for q in spec.traffic(c)["queries"]:
         queryspec.validate(q["plan"])
         assert set(queryspec.scanned_tables(q["plan"])) <= \
@@ -77,8 +78,17 @@ def test_end_to_end_bounds():
         assert m["source"] in ("host_clock", "device_trace")
 
 
-def test_readers_on_a_record():
+#: self seconds of a traced window's program spans: an op, the kernel
+#: call inside it and a load (which neither span metric reads)
+SPAN_SELF_S = {"ops.join": 0.5, "ops.join.probe": 0.25, "kernel.put": 0.125,
+               "kernel.fetch": 0.375, "loader.decode": 2.0}
+
+
+@pytest.mark.parametrize("span_self_s", [None, {}, SPAN_SELF_S],
+                         ids=["untraced", "no-spans", "spans"])
+def test_readers_on_a_record(span_self_s):
     record = cellrun.RunRecord(
+        span_self_s=span_self_s,
         peaks={"hbm_bytes_per_s": 1e9},
         trace={"busy_s": 0.5, "window_s": 2.0,
                "program_s": {"_hash_core": 0.01, "_segreduce_core": 0.02}},
@@ -98,6 +108,12 @@ def test_readers_on_a_record():
         100 * (100 * 16 + 40) / 1e9 / 0.02)
     assert read["data_plane.copied_bytes_per_row"] == 3.0
     assert read["loaders.load_s_per_query"] == 0.25
+    if span_self_s:
+        assert read["ops.host_s_per_query"] == 0.75
+        assert read["kdispatch.call_s_per_query"] == 0.5
+    else:
+        assert read["ops.host_s_per_query"] is None
+        assert read["kdispatch.call_s_per_query"] is None
 
 
 @pytest.mark.parametrize("misses", [0, 7])
