@@ -54,8 +54,9 @@ __all__ = [
     "BACKENDS", "REGISTRY", "Eligibility", "requested_backend",
     "active_backend", "eligible", "self_check", "device_calls",
     "reset_device_calls",
-    "hash_fixed", "combine_hashes", "hash_keys", "filter_join_gather",
-    "GROUPED_REDUCERS", "fp_includes_join", "fp_includes_group_by",
+    "hash_fixed", "combine_hashes", "hash_keys", "hash_join_probe",
+    "filter_join_gather", "GROUPED_REDUCERS", "fp_includes_join",
+    "fp_includes_group_by",
 ]
 
 BACKENDS = ("numpy", "pallas")
@@ -163,6 +164,10 @@ REGISTRY: Dict[str, Eligibility] = {
         "fused per-column mix + ordered combine over fixed-width key "
         "buffers; var-length (offsets, values) keys route to numpy "
         "structurally (not expressible as a dense block kernel)"),
+    "hash_join_probe": Eligibility(True,
+        "the build sorted by (hash, row id), a lower-bound search and run "
+        "length per probe hash: integer compares and counts only, so the "
+        "pairs and their order equal the stable argsort's"),
     "filter_join_gather": Eligibility(True,
         "index gather with -1 sentinel passthrough: no arithmetic"),
     "gather_payload": Eligibility(True,
@@ -237,6 +242,13 @@ def hash_keys(keys: Sequence, n: int) -> np.ndarray:
             and not any(isinstance(k, tuple) for k in keys)):
         return _on_device("hash_keys", keys, n)
     return vkernels.hash_keys(list(keys), n)
+
+
+def hash_join_probe(build_hash: np.ndarray, probe_hash: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    if _use_pallas("hash_join_probe"):
+        return _on_device("hash_join_probe", build_hash, probe_hash)
+    return vkernels.hash_join_probe(build_hash, probe_hash)
 
 
 def filter_join_gather(sel: np.ndarray, idx: np.ndarray) -> np.ndarray:
@@ -324,7 +336,7 @@ def fp_includes_join():
     if active_backend() == "pallas":
         rel = _pallas_mod
         deps = deps + (_tag(), rel.hash_fixed, rel.combine_hashes,
-                       rel.filter_join_gather)
+                       rel.hash_join_probe, rel.filter_join_gather)
     return deps
 
 
@@ -394,6 +406,14 @@ def self_check(n: int = 4096, n_groups: int = 97) -> Dict[str, str]:
           vkernels.combine_hashes(hs, n))
     ks = [cols["int64"], cols["float64"], cols["int32"]]
     check("hash_keys", rel.hash_keys(ks, n), vkernels.hash_keys(ks, n))
+    # many-to-many runs, and the largest and smallest hashes against a
+    # padded build that holds the largest too
+    bh = rng.integers(0, 400, n // 3).astype(np.uint64)
+    bh[:3] = [0, np.iinfo(np.uint64).max, np.iinfo(np.uint64).max]
+    ph = rng.integers(0, 600, n).astype(np.uint64)
+    ph[:2] = [0, np.iinfo(np.uint64).max]
+    check("hash_join_probe", rel.hash_join_probe(bh, ph),
+          vkernels.hash_join_probe(bh, ph))
     check("filter_join_gather", rel.filter_join_gather(sel, idx),
           vkernels.filter_join_gather(sel, idx))
     check("gather_payload", rel.gather_payload(cols["int64"], pidx, 0),

@@ -320,7 +320,7 @@ def _join_gather_indices(lb: RecordBatch, rb: RecordBatch,
         # null keys never match: probe/build over the valid-key subsets
         pidx = np.nonzero(lvalid)[0]
         bidx = np.nonzero(rvalid)[0]
-        pi, bi = vkernels.hash_join_probe(rh[bidx], lh[pidx])
+        pi, bi = kd.hash_join_probe(rh[bidx], lh[pidx])
     with obs.span(f"{span}.gather_idx"):
         li = kd.filter_join_gather(pidx, pi)
         ri = kd.filter_join_gather(bidx, bi)

@@ -21,7 +21,8 @@ from HBM, so the jitted forms below compile for the chip
 Each kernel has two parts:
 
   * a *core* (``_hash_core``, ``_combine_core``, ``_hash_keys_core``,
-    ``_gather_core``, ``_segreduce_core``): a jittable function of
+    ``_gather_core``, ``_probe_count_core``, ``_probe_expand_core``,
+    ``_segreduce_core``): a jittable function of
     arrays and static options, which the compile tests lower from shapes
     alone;
   * a numpy *edge* (the public functions, signatures mirroring
@@ -32,7 +33,9 @@ Each kernel has two parts:
     span (``core.obs``): ``kernel.put`` copies the padded arguments to
     the device, ``kernel.run`` runs the program, ``kernel.fetch`` copies
     the outputs back; the bytes that cross are counted in
-    ``kernel.h2d_bytes`` and ``kernel.d2h_bytes``.
+    ``kernel.h2d_bytes`` and ``kernel.d2h_bytes``.  The join probe's
+    edge alone runs two programs and leaves the first one's outputs on
+    the device for the second (``_run`` without ``_fetch``).
 
 Ported kernels:
 
@@ -47,6 +50,14 @@ Ported kernels:
       gather indices, ``-1`` miss sentinels preserved.
   ``gather_payload(values, idx, fill=0)``  ``out[i] = values[idx[i]]``
       with ``-1`` rows filled — the join output's payload gather.
+  ``hash_join_probe(build_hash, probe_hash)``  equal-hash candidate
+      pairs, probe-major with build rows ascending, in two stages:
+      ``_probe_count_core`` sorts the build by (hash, row id), finds
+      each probe's lower bound (``_lower_bound``: one broadcast compare,
+      then a gathered row of 64 pivots for each six further bits) and
+      its run length, and returns the pair count, the one value fetched;
+      ``_probe_expand_core``, sized by ``_bucket(count)``, writes the
+      pairs.
   ``grouped_count / grouped_sum / grouped_min / grouped_max(values,
   order, starts, valid=None)``  segment reducers over ``group_ranges``
       boundaries, with no segment ids and no search: rows are gathered
@@ -82,7 +93,7 @@ from repro.core import obs, vkernels
 
 __all__ = [
     "hash_fixed", "combine_hashes", "hash_keys",
-    "filter_join_gather", "gather_payload",
+    "filter_join_gather", "gather_payload", "hash_join_probe",
     "grouped_count", "grouped_sum", "grouped_min", "grouped_max",
     "platform",
 ]
@@ -123,26 +134,38 @@ def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
 
 
 def _nbytes(tree) -> int:
-    return sum(x.nbytes for x in jax.tree.leaves(tree))
+    """Bytes of the host arrays in ``tree`` (device arrays do not cross)."""
+    return sum(x.nbytes for x in jax.tree.leaves(tree)
+               if not isinstance(x, jax.Array))
 
 
-def _call(core, args, static):
+def _run(core, args, static):
     """``jax.jit(core)(*args, **static)`` on the default device under
-    64-bit mode, outputs copied back as numpy arrays.  The copy in, the
-    run and the copy out are each waited for, so each span holds its own
-    step's time."""
+    64-bit mode, outputs left there.  Host arguments are copied in;
+    device arguments (an earlier ``_run``'s outputs) stay where they
+    are.  The copy in and the run are each waited for, so each span
+    holds its own step's time."""
     with enable_x64():
         obs.add("kernel.h2d_bytes", _nbytes(args))
         with obs.span("kernel.put"):
             args = jax.block_until_ready(jax.device_put(args))
         with obs.span("kernel.run"):
-            out = jax.block_until_ready(
+            return jax.block_until_ready(
                 jax.jit(core, static_argnames=tuple(static))(*args,
                                                              **static))
-        with obs.span("kernel.fetch"):
-            out = jax.tree.map(np.asarray, out)
-        obs.add("kernel.d2h_bytes", _nbytes(out))
-        return out
+
+
+def _fetch(out):
+    """Device outputs copied back as numpy arrays."""
+    with obs.span("kernel.fetch"):
+        out = jax.tree.map(np.asarray, out)
+    obs.add("kernel.d2h_bytes", _nbytes(out))
+    return out
+
+
+def _call(core, args, static):
+    """``_run``, then every output copied back."""
+    return _fetch(_run(core, args, static))
 
 
 def _bits(values: np.ndarray) -> Tuple[np.ndarray, int]:
@@ -443,3 +466,145 @@ def grouped_max(values, order, starts, valid=None):
     """Per-group max over non-null rows (integer/bool) — bit-identical
     to ``vkernels.grouped_max`` including the all-null sentinel."""
     return _grouped_extreme("max", values, order, starts, valid)
+
+
+# --------------------------------------------------------------------------
+# join probe: the build side sorted on the device, every probe searched
+# --------------------------------------------------------------------------
+
+#: keys a row level of ``_lower_bound`` compares at once: 64 uint64
+#: keys are one gathered row of 128 32-bit lanes
+_FAN = 64
+#: bits of a lower bound that its first level resolves with no gather,
+#: by one broadcast compare against ``2**_TOP_BITS - 1`` pivots at most
+_TOP_BITS = 10
+
+
+def _halves(x):
+    """(high, low) uint32 halves of a 64-bit array, not negative if
+    signed: compared high first, they order as ``x`` does."""
+    x = x.astype(jnp.uint64)
+    return (x >> jnp.uint64(32)).astype(jnp.uint32), x.astype(jnp.uint32)
+
+
+def _less(ah, al, qh, ql):
+    return (ah < qh) | ((ah == qh) & (al < ql))
+
+
+def _count(flags):
+    """True flags per row of a 2-D bool array, as int64 (summed in 32
+    bits: 64-bit arithmetic over a 2-D array is slow to compile)."""
+    return flags.sum(1, dtype=jnp.int32).astype(jnp.int64)
+
+
+def _lower_bound(a, n, q):
+    """``(lo, hit)`` per query ``q[i]``: ``lo`` the number of ``a[:n]``
+    below it, ``hit`` whether ``a[lo] == q[i]`` with ``lo < n``.
+    ``a[:n]`` is sorted ascending; ``a`` and ``q`` are 64-bit, not
+    negative if signed, and ``len(a) >= 1024``.
+
+    Binary lifting with its steps grouped, and no ``while``: the first
+    level resolves up to ``_TOP_BITS`` bits of ``lo`` by one broadcast
+    compare against evenly spaced pivots; each further level gathers the
+    ``_FAN`` pivots of the query's window as one row of 128 32-bit
+    lanes, their high halves then their low halves, and counts those
+    below the query, resolving six more bits.  All 2-D work is on 32-bit
+    halves: 64-bit arithmetic over a 2-D array, or a gathered row
+    reshaped into (key, half) pairs, takes the TPU compiler over a
+    minute (compiling for a v5e on an 8-core x86 host).  The gathered rows are the program's largest buffer, 512
+    bytes a query.  The last level's row holds ``a[lo]``, which gives
+    ``hit``."""
+    rows = a.shape[0]
+    k = rows.bit_length()                  # 2**k > rows >= n: lo < 2**k
+    bits = _FAN.bit_length() - 1
+    width = 1 << bits * max(1, -(-(k - _TOP_BITS) // bits))
+    ah, al = _halves(jnp.concatenate([a, jnp.zeros((1 << k) - rows,
+                                                   a.dtype)]))
+    qh, ql = _halves(q)
+    top = (1 << k) // width
+    ph, pl = (x.reshape(top, width)[:top - 1, width - 1] for x in (ah, al))
+    real = jnp.arange(1, top, dtype=jnp.int64) * width <= n
+    lo = _count(_less(ph, pl, qh[:, None], ql[:, None]) & real) * width
+    c = lax.iota(jnp.int32, _FAN) + 1
+    while True:
+        # pivot c (from 1) of the window at lo: a[lo + c * step - 1]
+        step = width // _FAN
+        table = jnp.concatenate([x.reshape(-1, _FAN, step)[:, :, step - 1]
+                                 for x in (ah, al)], 1)
+        row = table[(lo // width).astype(jnp.int32)]
+        rh, rl = row[:, :_FAN], row[:, _FAN:]
+        real = c <= jnp.clip((n - lo) // step, 0, _FAN).astype(
+            jnp.int32)[:, None]
+        lo = lo + _count(_less(rh, rl, qh[:, None], ql[:, None])
+                         & real) * step
+        if step == 1:
+            # a[lo], when it is real, lies in this row
+            hit = ((rh == qh[:, None]) & (rl == ql[:, None]) & real).any(1)
+            return lo, hit
+        width = step
+
+
+def _probe_count_core(build, probe, nb, npr):
+    """Stage 1 of the probe: ``(total, ends, lo, order)``.  ``build`` and
+    ``probe`` are padded uint64 hashes with real lengths ``nb`` and
+    ``npr``.  The build sorts with its row ids, real rows first (padding
+    takes the largest key and sorts after every real row, so the search,
+    bounded by ``nb``, never reaches it whatever its value).  ``lo`` is
+    each probe's lower bound in the sorted build, ``ends`` the inclusive
+    prefix sum of each probe's equal-hash run length (0 for a miss and
+    for a padding probe), ``total`` its last element: the pair count."""
+    rows = build.shape[0]
+    ids = lax.iota(jnp.int64, rows)
+    keys, order = lax.sort(
+        (jnp.where(ids < nb, build,
+                   jnp.full_like(build, jnp.iinfo(build.dtype).max)), ids),
+        num_keys=1, is_stable=True)
+    # each sorted position's distance to the end of its equal-hash run:
+    # a min-scan from the right over the runs' last positions
+    last = (ids >= nb - 1) | (keys != jnp.roll(keys, -1))
+    (end,) = _scan(lambda a, b: (jnp.minimum(a[0], b[0]),),
+                   (jnp.where(last, ids, rows)[::-1],), (rows,))
+    run = end[::-1] - ids + 1
+    lo, hit = _lower_bound(keys, nb, probe)
+    hit &= lax.iota(jnp.int64, probe.shape[0]) < npr
+    (ends,) = _scan(lambda a, b: (a[0] + b[0],),
+                    (jnp.where(hit, _take(run, jnp.minimum(lo, rows - 1)),
+                               0),), (0,))
+    return ends[-1], ends, lo, order
+
+
+def _probe_expand_core(ends, lo, order, *, size: int):
+    """Stage 2: the pairs ``k < size``.  Pair k belongs to the probe
+    whose run of ``ends`` covers it, found by ``_lower_bound``, and
+    takes the build row ``order[lo + k - start]``.  Rows past the pair
+    count are sliced off by the edge."""
+    probes = ends.shape[0]
+    k = lax.iota(jnp.int64, size)
+    p = jnp.minimum(_lower_bound(ends, probes, k + 1)[0], probes - 1)
+    start = jnp.where(p > 0, _take(ends, jnp.maximum(p - 1, 0)), 0)
+    pos = _take(lo, p) + k - start
+    return p, _take(order, jnp.minimum(pos, order.shape[0] - 1))
+
+
+def hash_join_probe(build_hash: np.ndarray, probe_hash: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """Hash-equality candidate pairs ``(probe_idx, build_idx)``, int64,
+    probe-major with build indices ascending within each probe row —
+    bit-identical to ``vkernels.hash_join_probe``, duplicates and 64-bit
+    collisions included.  Two programs: ``_probe_count_core`` sorts the
+    build and searches every probe, and only its pair count comes back;
+    ``_probe_expand_core``, sized by that count, writes the pairs.  The
+    first program's per-probe arrays stay on the device between them."""
+    nb, npr = len(build_hash), len(probe_hash)
+    if nb == 0 or npr == 0:
+        return np.empty(0, np.int64), np.empty(0, np.int64)
+    total, *kept = _run(_probe_count_core, (
+        _pad(np.asarray(build_hash, dtype=np.uint64), _bucket(nb), 0),
+        _pad(np.asarray(probe_hash, dtype=np.uint64), _bucket(npr), 0),
+        np.int64(nb), np.int64(npr)), {})
+    total = int(_fetch(total))
+    if total == 0:
+        return np.empty(0, np.int64), np.empty(0, np.int64)
+    pi, bi = _call(_probe_expand_core, tuple(kept),
+                   {"size": _bucket(total)})
+    return pi[:total], bi[:total]

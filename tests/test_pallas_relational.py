@@ -238,6 +238,94 @@ def test_gather_payload_matches_naive(dtype):
 
 
 # ---------------------------------------------------------------------------
+# join probe: device kernel == numpy == nested loop
+# ---------------------------------------------------------------------------
+
+def py_hash_join_probe(build, probe):
+    """Every (probe row, build row) pair with equal hashes, probe-major
+    and build rows ascending: one scan of the whole build per probe row."""
+    pairs = [(i, j) for i, h in enumerate(probe)
+             for j in np.nonzero(build == h)[0]]
+    out = np.array(pairs, np.int64).reshape(-1, 2)
+    return out[:, 0].copy(), out[:, 1].copy()
+
+
+def _probe_case(rng, case):
+    """(build hashes, probe hashes) for a probe case: ``<nb>x<np>`` is
+    random hashes over a few hundred values (many-to-many runs) at those
+    lengths, the ``_bucket`` edges among them."""
+    top = np.uint64(_M64)
+    if case == "empty_build":
+        return np.empty(0, np.uint64), rng.integers(0, 9, 50, np.uint64)
+    if case == "empty_probe":
+        return rng.integers(0, 9, 50, np.uint64), np.empty(0, np.uint64)
+    if case == "no_match":
+        return (rng.integers(0, 1000, 700, np.uint64) * np.uint64(2),
+                rng.integers(0, 1000, 900, np.uint64) * np.uint64(2)
+                + np.uint64(1))
+    if case == "duplicates":
+        return (rng.integers(0, 5, 600, np.uint64),
+                rng.integers(0, 7, 400, np.uint64))
+    if case == "extremes_absent":
+        # the probe holds 0 and 2**64-1; the padded build holds neither,
+        # so no pair may come from its padding
+        return (rng.integers(1, 1000, 1100, np.uint64),
+                np.array([0, top, 5, 0, top], np.uint64))
+    if case == "extremes_present":
+        b = rng.integers(0, 50, 1100, np.uint64)
+        b[[3, 700, 1099]] = [top, 0, top]
+        p = rng.integers(0, 50, 1500, np.uint64)
+        p[[0, 1, 1499]] = [top, 0, top]
+        return b, p
+    if case == "build_larger":
+        return (rng.integers(0, 300, 5000, np.uint64),
+                rng.integers(0, 400, 300, np.uint64))
+    nb, npr = map(int, case.split("x"))
+    return (rng.integers(0, 300, nb, np.uint64),
+            rng.integers(0, 300, npr, np.uint64))
+
+
+_PROBE_CASES = ["empty_build", "empty_probe", "no_match", "duplicates",
+                "extremes_absent", "extremes_present", "build_larger",
+                "1023x1024", "1024x1025", "1025x1280", "1280x1281",
+                "1281x1023"]
+
+
+@pytest.mark.parametrize("case", _PROBE_CASES)
+def test_hash_join_probe_triple_identical(case):
+    rng = np.random.default_rng(sum(map(ord, case)))
+    build, probe = _probe_case(rng, case)
+    ref = vkernels.hash_join_probe(build, probe)
+    assert_bits(rel.hash_join_probe(build, probe), ref,
+                f"device hash_join_probe[{case}]")
+    assert_bits(ref, py_hash_join_probe(build, probe),
+                f"numpy hash_join_probe[{case}]")
+
+
+def test_hash_join_probe_searches_past_one_row_level():
+    """Builds and probe prefixes of 2**17 rows and more take two row
+    levels of the search, and duplicate runs cross the rows' edges."""
+    rng = np.random.default_rng(13)
+    for nb, npr, card in ((70_000, 3_000, 40_000), (2_000, 140_000, 1_500),
+                          (66_000, 70_000, 300)):
+        build = rng.integers(0, card, nb, np.uint64)
+        probe = rng.integers(0, card, npr, np.uint64)
+        assert_bits(rel.hash_join_probe(build, probe),
+                    vkernels.hash_join_probe(build, probe), f"{nb}x{npr}")
+
+
+def test_dispatch_hash_join_probe_serves_both_backends(monkeypatch):
+    rng = np.random.default_rng(12)
+    build, probe = _probe_case(rng, "duplicates")
+    want = vkernels.hash_join_probe(build, probe)
+    for backend in ("numpy", "pallas"):
+        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", backend)
+        kd.reset_device_calls()
+        assert_bits(kd.hash_join_probe(build, probe), want, backend)
+        assert sum(kd.device_calls().values()) == (backend == "pallas")
+
+
+# ---------------------------------------------------------------------------
 # segment reducers: dtype sweep x null fraction x group shape
 # ---------------------------------------------------------------------------
 
@@ -443,9 +531,12 @@ def test_device_calls_counted_per_kernel_and_platform(pallas_backend):
     kd.hash_fixed(v)
     kd.GROUPED_REDUCERS["sum"](v, order, starts)
     kd.GROUPED_REDUCERS["sum"](v.astype(np.float64), order, starts)
+    h = vkernels.hash_fixed(v)
+    kd.hash_join_probe(h[:100], h)
     plat = rel.platform()
     assert kd.device_calls() == {("hash_fixed", plat): 2,
-                                 ("grouped_sum", plat): 1}, \
+                                 ("grouped_sum", plat): 1,
+                                 ("hash_join_probe", plat): 1}, \
         "the float sum is registry-ineligible and must not count"
     kd.reset_device_calls()
     assert kd.device_calls() == {}
@@ -506,16 +597,29 @@ def test_backend_flip_changes_only_relational_fingerprints(monkeypatch):
 def test_pallas_kernel_edit_invalidates_join_cone(monkeypatch):
     monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
     fp1 = code_fingerprint(ops.join)
+    fj1 = code_fingerprint(ops.filter_join)
     gb1 = code_fingerprint(ops.group_by)
 
     def other_hash_fixed(values):
         return vkernels.hash_fixed(values)
 
     monkeypatch.setattr(rel, "hash_fixed", other_hash_fixed)
-    assert code_fingerprint(ops.join) != fp1, \
+    fp2 = code_fingerprint(ops.join)
+    fj2 = code_fingerprint(ops.filter_join)
+    assert fp2 != fp1 and fj2 != fj1, \
         "editing a pallas hash kernel must invalidate join cones"
     assert code_fingerprint(ops.group_by) == gb1, \
         "the hash kernel is not a group_by dependency"
+
+    def other_probe(build_hash, probe_hash):
+        return vkernels.hash_join_probe(build_hash, probe_hash)
+
+    monkeypatch.setattr(rel, "hash_join_probe", other_probe)
+    assert code_fingerprint(ops.join) != fp2, \
+        "editing the device probe must invalidate join cones"
+    assert code_fingerprint(ops.filter_join) != fj2
+    assert code_fingerprint(ops.group_by) == gb1, \
+        "the probe is not a group_by dependency"
 
 
 def _select_amount(tables):
@@ -676,3 +780,20 @@ if HAVE_HYPOTHESIS:
             if len(sel) else np.full(n, -1, np.int64)
         assert_bits(rel.filter_join_gather(sel, idx),
                     vkernels.filter_join_gather(sel, idx))
+
+    @pytest.mark.stress
+    @settings(max_examples=int(os.environ.get("ZERROW_HYP_EXAMPLES",
+                                              "25")), deadline=None)
+    @given(st.integers(0, 2**31 - 1), st.integers(0, 1300),
+           st.integers(0, 1300), st.integers(1, 2000))
+    def test_hash_join_probe_matches_hypothesis(seed, nb, npr, card):
+        rng = np.random.default_rng(seed)
+        top = np.uint64(_M64)
+        build = rng.integers(0, card, nb, np.uint64)
+        probe = rng.integers(0, card, npr, np.uint64)
+        # the extremes, sometimes: a real 0 or 2**64-1 beside the padding
+        build[rng.random(nb) < 0.02] = top
+        probe[rng.random(npr) < 0.02] = top
+        want = vkernels.hash_join_probe(build, probe)
+        assert_bits(rel.hash_join_probe(build, probe), want)
+        assert_bits(want, py_hash_join_probe(build, probe))

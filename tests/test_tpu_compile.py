@@ -4,7 +4,8 @@ Each kernel core of ``repro.kernels.relational`` — everything the star
 join + group-by runs on the device — is lowered from shapes alone and
 compiled by the TPU compiler for a *described* v5e (no chip attached):
 2^23 rows, a 2^20-row gather source and 1024 groups, the sizes
-``chip_smoke.py`` drives on the chip.  What the chip's compiler refuses
+``chip_smoke.py`` drives on the chip; the join probe searches 2^23
+probe hashes in an 8,192-row build and expands 2^20 pairs.  What the chip's compiler refuses
 (an unsupported 64-bit op, a program that does not fit in HBM) fails
 here at no chip time.
 
@@ -25,6 +26,8 @@ from repro.kernels import relational as rel  # noqa: E402
 ROWS = 1 << 23
 SOURCE_ROWS = 1 << 20
 GROUPS = 1024
+BUILD_ROWS = 1 << 13            # a filtered dimension: SSB's part side
+PAIRS = 1 << 20
 HBM_BYTES = 16 * 10**9          # one v5e chip
 
 
@@ -66,6 +69,15 @@ def _cases(s):
     starts = _spec((rel._bucket(GROUPS),), i64, s)
     valid = _spec((rel._bucket(ROWS),), jnp.bool_, s)
     n = _spec((), i64, s)
+    build = _spec((rel._bucket(BUILD_ROWS),), u64, s)
+    probe = {
+        "hash_join_probe:count": (rel._probe_count_core,
+                                  (build, rows, n, n), {}),
+        "hash_join_probe:expand": (rel._probe_expand_core,
+                                   (idx, idx, _spec((rel._bucket(BUILD_ROWS),),
+                                                    i64, s)),
+                                   {"size": rel._bucket(PAIRS)}),
+    }
     seg = {f"grouped_{op}": (rel._segreduce_core,
                              (None if op == "count" else idx, order,
                               starts, valid, n), {"op": op})
@@ -78,11 +90,13 @@ def _cases(s):
                       {"signs": (0, 1 << 63)}),
         "filter_join_gather": (rel._gather_core, (src, idx, fill), {}),
         "gather_payload": (rel._gather_core, (src, idx, fill), {}),
+        **probe,
         **seg,
     }
 
 
 KERNELS = ("hash_fixed", "hash_fixed:float", "combine_hashes", "hash_keys",
+           "hash_join_probe:count", "hash_join_probe:expand",
            "filter_join_gather", "gather_payload", "grouped_count",
            "grouped_sum", "grouped_min", "grouped_max")
 
@@ -128,10 +142,23 @@ def test_segment_reducer_lowers_without_loop_or_scatter(one_chip, op,
         assert "stablehlo.scatter" not in hlo
 
 
+@pytest.mark.parametrize("stage", ["count", "expand"])
+def test_join_probe_lowers_without_loop(one_chip, stage):
+    """The probe's searches are static levels and its prefix sums
+    doubling passes: no ``while`` (the segment reducer's old ``while``
+    search of 64-bit gathers took 5.5 s a call on a v5e)."""
+    with enable_x64():
+        core, args, static = _cases(one_chip)[f"hash_join_probe:{stage}"]
+        hlo = jax.jit(core, static_argnames=tuple(static)) \
+            .lower(*args, **static).as_text()
+    assert "stablehlo.while" not in hlo
+
+
 def test_cases_cover_every_registered_device_kernel():
     from repro.core import kdispatch
     eligible = {k.split(":")[0] for k, e in kdispatch.REGISTRY.items()
                 if e.eligible}
     assert eligible == {k.split(":")[0] for k in KERNELS}
     # the real sizes are their own buckets: no padding at the smoke's size
-    assert all(rel._bucket(n) == n for n in (ROWS, SOURCE_ROWS, GROUPS))
+    assert all(rel._bucket(n) == n
+               for n in (ROWS, SOURCE_ROWS, GROUPS, BUILD_ROWS, PAIRS))
