@@ -25,13 +25,9 @@ import sys
 #: cold run, with queries served concurrently throughout; 'serve_load'
 #: asserts the overload/fault story — typed shed outcomes, a balanced
 #: admission ledger, bounded fault-arm p99, and zero wrong results
-#: while workers are being killed mid-request; 'pallas_join' asserts
-#: the device kernel backend produces bit-identical join+group_by
-#: aggregates to the numpy pipeline and that the kdispatch self-check
-#: passes.
+#: while workers are being killed mid-request.
 SMOKE_FIGURES = ("fig2", "fig6", "concurrency", "flight", "diffcache",
-                 "kernels", "join", "query", "ingest", "serve_load",
-                 "pallas_join")
+                 "kernels", "join", "query", "ingest", "serve_load")
 
 
 def main() -> None:
@@ -45,8 +41,7 @@ def main() -> None:
     enable_compile_cache()
     from . import (bench_concurrency, bench_diffcache, bench_flight,
                    bench_ingest, bench_join, bench_kernels,
-                   bench_pallas_join, bench_query,
-                   bench_serve_load, fig2_copy_latency,
+                   bench_query, bench_serve_load, fig2_copy_latency,
                    fig4_copy_avoidance, fig5_decache, fig6_resharing,
                    fig7_depth, fig8_dict_repeats, fig9_dict_norepeats,
                    fig10_eviction, roofline_table)
@@ -65,7 +60,6 @@ def main() -> None:
         "diffcache": bench_diffcache.main,    # cross-run differential cache
         "kernels": bench_kernels.main,        # vectorized kernels + scaling
         "join": bench_join.main,              # hash join + group-by engine
-        "pallas_join": bench_pallas_join.main,  # accelerator kernel backend
         "query": bench_query.main,            # plan frontend + optimizer
         "ingest": bench_ingest.main,          # streaming ingest + serving
         "serve_load": bench_serve_load.main,  # overload + fault resilience

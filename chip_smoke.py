@@ -20,7 +20,8 @@ Phases, in order; any failure exits nonzero and prints no result:
   4. plan      — ``scan`` -> ``join`` (left, on cust) -> ``group_by``
                  (country: sum/min/max/count of amount), ``compile_plans``,
                  ``Executor.run`` in thread mode: once with
-                 ``ZERROW_KERNEL_BACKEND=numpy``, once with ``pallas``.
+                 ``ZERROW_KERNEL_BACKEND=numpy``, once with the device
+                 backend (``kdispatch.DEVICE_BACKEND``).
   5. compare   — aggregate buffers (values and validity) bit for bit,
                  every kernel on the path served calls on the TPU; prints
                  the per-kernel device call counts, compile seconds and
@@ -52,7 +53,8 @@ from repro.core import (BufferStore, Executor, RMConfig,  # noqa: E402
 from repro.core.arrow import Column, Table  # noqa: E402
 from repro.core.plan import compile_plans, scan  # noqa: E402
 
-_BACKEND_ENV = "ZERROW_KERNEL_BACKEND"
+_BACKEND_ENV = kdispatch.ENV
+_DEVICE = kdispatch.DEVICE_BACKEND
 _INTERPRET_ENV = "ZERROW_PALLAS_INTERPRET"
 
 ORDERS_LOG2 = 23
@@ -147,9 +149,9 @@ def run_arm(backend: str, orders_path: str, customers_path: str,
     return {"wall_s": wall, "aggregates": snap}
 
 
-def compare(numpy_arm: dict, pallas_arm: dict, n_orders: int) -> None:
+def compare(numpy_arm: dict, device_arm: dict, n_orders: int) -> None:
     """Phase 5: bit identity, and sanity of the reference itself."""
-    a, b = numpy_arm["aggregates"], pallas_arm["aggregates"]
+    a, b = numpy_arm["aggregates"], device_arm["aggregates"]
     if a.keys() != b.keys():
         raise AssertionError(f"columns differ: {sorted(a)} vs {sorted(b)}")
     for name in a:
@@ -157,7 +159,7 @@ def compare(numpy_arm: dict, pallas_arm: dict, n_orders: int) -> None:
             (va, ma), (vb, mb) = a[name], b[name]
             if va.dtype != vb.dtype or va.tobytes() != vb.tobytes() \
                     or not np.array_equal(ma, mb):
-                raise AssertionError(f"{name}: pallas aggregate bits "
+                raise AssertionError(f"{name}: device aggregate bits "
                                      f"differ from numpy")
         elif a[name] != b[name]:
             raise AssertionError(f"{name}: group keys differ")
@@ -178,7 +180,7 @@ def run_star(workdir: str, seed: int, platform: str,
                                              customers_log2)
     report = {}
     arms = {}
-    for backend in ("numpy", "pallas"):
+    for backend in ("numpy", _DEVICE):
         kdispatch.reset_device_calls()
         c0 = compile_s()
         arms[backend] = run_arm(backend, orders_path, customers_path,
@@ -188,7 +190,7 @@ def run_star(workdir: str, seed: int, platform: str,
     calls = kdispatch.device_calls()
     report["device_calls"] = {k: calls.get((k, platform), 0)
                               for k in PATH_KERNELS}
-    compare(arms["numpy"], arms["pallas"], 1 << orders_log2)
+    compare(arms["numpy"], arms[_DEVICE], 1 << orders_log2)
     missing = [k for k, n in report["device_calls"].items() if n == 0]
     if missing:
         raise AssertionError(f"path kernels served no call on "
@@ -209,15 +211,13 @@ def main(argv=None) -> None:
     print(f"device: {dev.platform} / {dev.device_kind} / {n_dev} device(s)")
     print(f"compile cache: {cache_dir}")
 
-    os.environ[_BACKEND_ENV] = "pallas"
+    os.environ[_BACKEND_ENV] = _DEVICE
     try:
         checked = kdispatch.self_check()
     finally:
         os.environ.pop(_BACKEND_ENV, None)
-    print(f"self_check: {sum(v == 'ok' for v in checked.values())} "
-          f"kernels bit-identical on the chip, "
-          f"{sum(v.startswith('ineligible') for v in checked.values())} "
-          f"documented ineligible; compile {compile_s():.3f} s")
+    print(f"self_check: {len(checked)} kernels bit-identical on the chip "
+          f"({', '.join(checked)}); compile {compile_s():.3f} s")
 
     workdir = tempfile.mkdtemp(prefix="zerrow-chip-smoke-")
     try:
@@ -225,15 +225,15 @@ def main(argv=None) -> None:
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     print(f"star join + group_by (2^{ORDERS_LOG2} orders, "
-          f"2^{CUSTOMERS_LOG2} customers, seed {args.seed}): pallas "
+          f"2^{CUSTOMERS_LOG2} customers, seed {args.seed}): device "
           f"aggregates bit-identical to numpy")
     print(f"device calls on {dev.platform}: "
           + ", ".join(f"{k}={n}" for k, n in report["device_calls"].items()))
-    print("not a benchmark — wall includes loads and the pallas arm's "
+    print("not a benchmark — wall includes loads and the device arm's "
           "compiles: " + ", ".join(
               f"{b} wall {report[f'{b}_wall_s']:.3f} s (compile "
               f"{report[f'{b}_compile_s']:.3f} s)"
-              for b in ("numpy", "pallas")))
+              for b in ("numpy", _DEVICE)))
     print(f"compile total: {compile_s():.3f} s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -205,7 +205,7 @@ def _code_fingerprint(fn, _seen=None) -> Optional[str]:
     # *callable* __fp_includes__ is invoked at fingerprint time to
     # produce the tuple — how the relational ops bind to whichever
     # kernel backend ``ZERROW_KERNEL_BACKEND`` currently selects, so a
-    # backend flip changes the fingerprint (kdispatch.fp_includes_join)
+    # backend flip changes the fingerprint (kdispatch.fp_includes)
     includes = getattr(fn, "__fp_includes__", ()) or ()
     if callable(includes):
         includes = includes() or ()

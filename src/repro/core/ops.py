@@ -55,6 +55,7 @@ hand-wired one exercise the identical op (and fingerprint) surface.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -564,12 +565,12 @@ def filter_join_node(tables: Sequence[Table], on, how: str = "inner",
 #: them here makes a kernel edit invalidate every cached join/group-by
 #: output (differential reruns recompute the affected side).  The
 #: declarations are *callables* resolved at fingerprint time by
-#: ``kdispatch``: they always fold in the numpy vkernels and, when
-#: ``ZERROW_KERNEL_BACKEND=pallas`` is active, additionally the backend
-#: tag + live Pallas kernels — so flipping the backend or editing a
-#: Pallas kernel invalidates exactly these cones too
-join.__fp_includes__ = kd.fp_includes_join
-group_by.__fp_includes__ = kd.fp_includes_group_by
+#: ``kdispatch``: they always fold in the numpy vkernels and, under the
+#: device backend, additionally the backend tag + live device kernels —
+#: so flipping the backend or editing a device kernel invalidates
+#: exactly these cones too
+join.__fp_includes__ = functools.partial(kd.fp_includes, "join")
+group_by.__fp_includes__ = functools.partial(kd.fp_includes, "group_by")
 join_node.__fp_includes__ = join.__fp_includes__
 group_by_node.__fp_includes__ = group_by.__fp_includes__
 #: fused and unfused plans fingerprint distinctly: filter_join's own code

@@ -689,13 +689,13 @@ class WorkerPoolExecutor:
 
 
 def _refuse_device_backend() -> None:
-    if kdispatch.requested_backend() == "pallas":
+    if kdispatch.device_requested():
         raise ValueError(
-            "ProcessWorkerExecutor cannot serve ZERROW_KERNEL_BACKEND="
-            "pallas: the chip belongs to this process and Flight workers "
-            "run on the CPU (JAX_PLATFORMS=cpu). Use the thread executor "
-            "(workers_mode='thread') for device kernels, or "
-            "ZERROW_KERNEL_BACKEND=numpy.")
+            f"ProcessWorkerExecutor cannot serve {kdispatch.ENV}="
+            f"{kdispatch.DEVICE_BACKEND}: the chip belongs to this process "
+            f"and Flight workers run on the CPU (JAX_PLATFORMS=cpu). Use "
+            f"the thread executor (workers_mode='thread') for device "
+            f"kernels, or {kdispatch.ENV}=numpy.")
 
 
 class ProcessWorkerExecutor(WorkerPoolExecutor):
@@ -720,7 +720,7 @@ class ProcessWorkerExecutor(WorkerPoolExecutor):
 
     Workers run on the host only (spawned with ``JAX_PLATFORMS=cpu``):
     a chip belongs to one process, so this executor refuses the device
-    kernel backend (``ZERROW_KERNEL_BACKEND=pallas``) rather than run
+    kernel backend (``kdispatch.device_requested()``) rather than run
     the relational kernels on worker CPUs; device pipelines use the
     thread executor.
     """

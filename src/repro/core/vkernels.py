@@ -78,14 +78,14 @@ via ``__fp_includes__`` (``ops.filter_join`` chains down to
 plan outputs exactly as it invalidates hand-wired ones.
 
 This module is also the *reference semantics* for the accelerator
-backend: ``core/kdispatch.py`` (``ZERROW_KERNEL_BACKEND=pallas``) may
-route hashing, join gathers, and the integer segment reducers to the
-Pallas ports in ``repro.kernels.relational`` — but only kernels the
-differential harness proves bit-identical to the functions here are
-admitted, and order-sensitive float reductions (``grouped_sum``'s
-sequential ``np.bincount`` accumulation, ``reduceat`` extreme ties)
-stay on this code path by registry.  Behavior changes here are contract
-changes for both backends.
+backend: under the device backend ``core/kdispatch.py`` may route
+hashing, the join probe and gathers, and the integer segment reducers
+to the device kernels in ``repro.kernels.relational`` — but only kernels
+the differential harness proves bit-identical to the functions here
+enter its kernel table, and order-sensitive float reductions
+(``grouped_sum``'s sequential ``np.bincount`` accumulation, ``reduceat``
+extreme ties) stay on this code path by the table's admission rule.
+Behavior changes here are contract changes for both backends.
 """
 
 from __future__ import annotations

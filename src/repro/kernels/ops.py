@@ -26,10 +26,7 @@ try:
 except ImportError as e:                                # pragma: no cover
     raise ImportError(
         "repro.kernels requires jax (with Pallas), which is not "
-        "importable here. The relational pipeline does not need it: "
-        "leave ZERROW_KERNEL_BACKEND unset (or set it to 'numpy') to "
-        "run on the numpy vkernels; set ZERROW_KERNEL_BACKEND=pallas "
-        "only where jax is installed."
+        "importable here."
     ) from e
 
 import numpy as np

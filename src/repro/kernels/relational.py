@@ -1,14 +1,15 @@
 """Device-resident relational kernels: the eligible ``core/vkernels.py``
 hot paths as jitted XLA programs on jax's default device.
 
-``ZERROW_KERNEL_BACKEND=pallas`` (``core/kdispatch.py``) routes the
-join/group-by hot path here, so it runs on the accelerator instead of
-host numpy.  Every kernel is held to a **bit-identity** contract against
-its numpy reference — admission is decided by the differential harness
-in ``tests/test_pallas_relational.py`` and recorded in
-``core.kdispatch.REGISTRY``; anything that cannot reproduce the numpy
+The device backend of ``core/kdispatch.py`` routes the join/group-by
+hot path here, so it runs on the accelerator instead of host numpy.
+Every kernel is held to a **bit-identity** contract against its numpy
+reference — admission is decided by the differential harness in
+``tests/test_pallas_relational.py`` and recorded in
+``core.kdispatch.KERNELS``; anything that cannot reproduce the numpy
 bits exactly (float segment sums: PR 5's sequential-accumulation
-contract) stays on numpy *by registry*, never silently.
+contract) stays on numpy *by the table's admission rule*, never
+silently.
 
 Why XLA and not Pallas: every kernel here works in 64-bit lanes
 (splitmix64 over uint64, int64 gather indices, int64 accumulators), and
@@ -20,11 +21,10 @@ from HBM, so the jitted forms below compile for the chip
 
 Each kernel has two parts:
 
-  * a *core* (``_hash_core``, ``_combine_core``, ``_hash_keys_core``,
-    ``_gather_core``, ``_probe_count_core``, ``_probe_expand_core``,
-    ``_segreduce_core``): a jittable function of
-    arrays and static options, which the compile tests lower from shapes
-    alone;
+  * a *core* (``_hash_core``, ``_combine_core``, ``_gather_core``,
+    ``_probe_count_core``, ``_probe_expand_core``, ``_segreduce_core``):
+    a jittable function of arrays and static options, which the compile
+    tests lower from shapes alone;
   * a numpy *edge* (the public functions, signatures mirroring
     ``core/vkernels.py``): it views the inputs' raw bits, pads them to a
     bucketed length (``_bucket``) so a new row count rarely means a new
@@ -43,13 +43,8 @@ Ported kernels:
       fixed-width array (float -0.0 canonicalized, as numpy does).
   ``combine_hashes(hs,n)`` order-sensitive fold of per-column uint64
       hashes into one row hash (the representation-free combiner).
-  ``hash_keys(keys, n)``   per-column splitmix64 + the ordered combine in
-      one program over fixed-width key buffers; var-length (offsets,
-      values) keys are routed to numpy by the dispatch layer.
   ``filter_join_gather(sel, idx)``  compose a filter selection with join
       gather indices, ``-1`` miss sentinels preserved.
-  ``gather_payload(values, idx, fill=0)``  ``out[i] = values[idx[i]]``
-      with ``-1`` rows filled — the join output's payload gather.
   ``hash_join_probe(build_hash, probe_hash)``  equal-hash candidate
       pairs, probe-major with build rows ascending, in two stages:
       ``_probe_count_core`` sorts the build by (hash, row id), finds
@@ -68,14 +63,14 @@ Ported kernels:
       ``reduceat`` even where the prefix itself overflows; min/max is a
       scan segmented by head flags set at the starts, read at each
       group's last row.  Integer/bool reductions hold bit-identity;
-      float sum/min/max are order- or tie-sensitive and are
-      registry-ineligible.
+      float sum/min/max are order- or tie-sensitive and the kernel
+      table refuses them.
 
 The cores are plain module functions, jitted at the call (jax reuses the
 compiled program for the same function, options and shapes) rather than
 held as module-level jit objects, so ``core.fingerprint``'s direct-
 global scan sees their code: editing a kernel here invalidates every
-cached join/group-by cone computed with ``ZERROW_KERNEL_BACKEND=pallas``.
+cached join/group-by cone computed under the device backend.
 """
 
 from __future__ import annotations
@@ -92,8 +87,7 @@ from repro.compat import enable_x64
 from repro.core import obs, vkernels
 
 __all__ = [
-    "hash_fixed", "combine_hashes", "hash_keys",
-    "filter_join_gather", "gather_payload", "hash_join_probe",
+    "hash_fixed", "combine_hashes", "filter_join_gather", "hash_join_probe",
     "grouped_count", "grouped_sum", "grouped_min", "grouped_max",
     "platform",
 ]
@@ -180,7 +174,7 @@ def _bits(values: np.ndarray) -> Tuple[np.ndarray, int]:
 
 
 # --------------------------------------------------------------------------
-# splitmix64: hash_fixed / combine_hashes / hash_keys
+# splitmix64: hash_fixed / combine_hashes
 # --------------------------------------------------------------------------
 
 def _mix64(h):
@@ -212,12 +206,6 @@ def _combine_core(hashes):
     return h
 
 
-def _hash_keys_core(bits, *, signs: Tuple[int, ...]):
-    """``_hash_core`` per key column, then the ordered combine."""
-    return _combine_core(tuple(_hash_core(b, sign=s)
-                               for b, s in zip(bits, signs)))
-
-
 def hash_fixed(values: np.ndarray) -> np.ndarray:
     """uint64 splitmix64 hash per element of a fixed-width array —
     bit-identical to ``vkernels.hash_fixed``."""
@@ -240,26 +228,8 @@ def combine_hashes(col_hashes: Sequence[np.ndarray], n: int) -> np.ndarray:
     return _call(_combine_core, (hs,), {})[:n]
 
 
-def hash_keys(keys: Sequence[np.ndarray], n: int) -> np.ndarray:
-    """Per-column splitmix64 and the ordered combine in one program over
-    *fixed-width* key buffers — bit-identical to ``vkernels.hash_keys``
-    on ndarray keys.  Var-length ``(offsets, values)`` keys are not
-    expressible here; the dispatch layer routes any mix containing one
-    to numpy."""
-    if any(isinstance(k, tuple) for k in keys):
-        raise TypeError("hash_keys (device) takes fixed-width ndarray "
-                        "keys only; var-length keys stay on numpy")
-    if not keys or n == 0:
-        return vkernels.hash_keys(list(keys), n)
-    size = _bucket(n)
-    bits, signs = zip(*(_bits(k) for k in keys))
-    return _call(_hash_keys_core,
-                 (tuple(_pad(b, size, 0) for b in bits),),
-                 {"signs": tuple(signs)})[:n]
-
-
 # --------------------------------------------------------------------------
-# gathers: filter->join index composition + payload gather
+# gather: filter->join index composition
 # --------------------------------------------------------------------------
 
 def _gather_core(src, idx, fill):
@@ -269,33 +239,21 @@ def _gather_core(src, idx, fill):
     return jnp.where(hit, g, fill)
 
 
-def _sentinel_gather(src: np.ndarray, idx: np.ndarray, fill) -> np.ndarray:
-    """The shared edge of ``filter_join_gather`` and ``gather_payload``."""
-    m = len(idx)
-    if m == 0:
-        return np.empty(0, dtype=src.dtype)
-    if len(src) == 0:
-        # every index must be a -1 miss sentinel; nothing to gather
-        return np.full(m, fill, dtype=src.dtype)
-    return _call(_gather_core,
-                 (_pad(src, _bucket(len(src)), 0),
-                  _pad(np.asarray(idx, dtype=np.int64), _bucket(m), -1),
-                  np.asarray(fill, dtype=src.dtype)), {})[:m]
-
-
 def filter_join_gather(sel: np.ndarray, idx: np.ndarray) -> np.ndarray:
     """Compose a filter's row selection with a join's gather indices,
     ``-1`` left-join miss sentinels preserved — bit-identical to
     ``vkernels.filter_join_gather``."""
-    return _sentinel_gather(np.asarray(sel, dtype=np.int64), idx, -1)
-
-
-def gather_payload(values: np.ndarray, idx: np.ndarray,
-                   fill=0) -> np.ndarray:
-    """Join-payload gather: ``out[i] = values[idx[i]]`` with
-    ``idx[i] == -1`` rows set to ``fill`` — the 1-D, sentinel-aware
-    sibling of ``take_rows`` used to materialize join output columns."""
-    return _sentinel_gather(np.asarray(values), idx, fill)
+    sel = np.asarray(sel, dtype=np.int64)
+    m = len(idx)
+    if m == 0:
+        return np.empty(0, dtype=np.int64)
+    if len(sel) == 0:
+        # every index must be a -1 miss sentinel; nothing to gather
+        return np.full(m, -1, dtype=np.int64)
+    return _call(_gather_core,
+                 (_pad(sel, _bucket(len(sel)), 0),
+                  _pad(np.asarray(idx, dtype=np.int64), _bucket(m), -1),
+                  np.asarray(-1, dtype=np.int64)), {})[:m]
 
 
 # --------------------------------------------------------------------------
@@ -426,13 +384,13 @@ def grouped_sum(values: np.ndarray, order: np.ndarray,
     ``vkernels.grouped_sum``'s ``reduceat`` even past overflow.  Float
     inputs are rejected: PR 5's contract accumulates float sums
     sequentially in original row order (``np.bincount``), which a
-    parallel reduction cannot reproduce bit-for-bit — the registry keeps
-    them on numpy (the documented-ineligible entry)."""
+    parallel reduction cannot reproduce bit-for-bit — the kernel table's
+    admission rule keeps them on numpy."""
     if not (values.dtype == np.bool_
             or np.issubdtype(values.dtype, np.integer)):
         raise TypeError(
             f"grouped_sum (device) is integer/bool only; {values.dtype} "
-            "sums are order-sensitive and registry-ineligible")
+            "sums are order-sensitive and stay on numpy")
     if len(starts) == 0:
         acc = np.uint64 if values.dtype == np.uint64 else np.int64
         return np.empty(0, acc), np.empty(0, np.int64)
@@ -448,8 +406,8 @@ def _grouped_extreme(op, values, order, starts, valid):
     if np.issubdtype(values.dtype, np.floating):
         raise TypeError(
             f"grouped_{op} (device) is integer/bool only; float "
-            "-0.0/NaN tie-breaking is order-sensitive and "
-            "registry-ineligible")
+            "-0.0/NaN tie-breaking is order-sensitive and stays on "
+            "numpy")
     v = _int_view(values)
     if len(starts) == 0:
         return np.empty(0, v.dtype), np.empty(0, np.int64)

@@ -87,7 +87,7 @@ from repro.kernels import relational as rel  # noqa: E402
 
 @pytest.fixture
 def pallas_backend(monkeypatch):
-    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+    monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
 
 
 def _moved(fn, *args):

@@ -1,19 +1,20 @@
-"""Eligibility harness for the device relational kernels.
+"""Admission harness for the device relational kernels.
 
-Admission to the ``ZERROW_KERNEL_BACKEND=pallas`` path is decided here:
-every kernel in ``repro.kernels.relational`` must be **bit-identical**
-(values AND dtypes) to its ``core/vkernels.py`` numpy reference, which
-must itself match a per-row naive Python reference — across dtype mixes
-(int32/int64/uint64/float64 including -0.0 and NaN), null fractions,
-duplicate-heavy keys, zero-row and empty(all-null)-group inputs.
-Kernels the registry marks ineligible (the order-sensitive float
-reductions) are asserted to *stay* on the numpy path even with the
-pallas backend active, ``self_check`` is proven to raise on a kernel
-whose bits regress, and a backend that cannot load raises instead of
-serving numpy.  The fingerprint section pins the cache
-contract: flipping the backend or editing a Pallas kernel invalidates
-exactly the join/group-by cones (manifest adoption counts asserted,
-mirroring the PR 5 ``__fp_includes__`` tests).
+Admission to the device backend's kernel table (``kdispatch.KERNELS``)
+is decided here: every kernel in ``repro.kernels.relational`` must be
+**bit-identical** (values AND dtypes) to its ``core/vkernels.py`` numpy
+reference, which must itself match a per-row naive Python reference —
+across dtype mixes (int32/int64/uint64/float64 including -0.0 and NaN),
+null fractions, duplicate-heavy keys, zero-row and empty(all-null)-group
+inputs.  Every kernel of the table is dispatched under both backends;
+the arguments its admission rule refuses (the order-sensitive float
+reductions) are asserted to *stay* on the numpy path with the device
+backend active, ``self_check`` is proven to raise on a kernel whose bits
+regress, and a backend that cannot load raises instead of serving
+numpy.  The fingerprint section pins the cache contract: flipping the
+backend or editing a device kernel invalidates exactly the
+join/group-by cones (manifest adoption counts asserted, mirroring the
+relational ops' ``__fp_includes__`` tests).
 
 The kernels run on jax's default device: the CPU under
 ``JAX_PLATFORMS=cpu`` here, the TPU on the chip.  The seeded-numpy sweeps
@@ -45,7 +46,7 @@ _M64 = (1 << 64) - 1
 
 @pytest.fixture
 def pallas_backend(monkeypatch):
-    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+    monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
 
 
 # ---------------------------------------------------------------------------
@@ -177,29 +178,8 @@ def test_combine_hashes_triple_identical():
                 vkernels.combine_hashes(zero, 0))
 
 
-def test_hash_keys_fused_matches_composition():
-    rng = np.random.default_rng(2)
-    n = 3000
-    keys = [rng.integers(-9, 9, n).astype(np.int64),
-            (rng.integers(0, 5, n).astype(np.float64) / 2),
-            rng.integers(0, 1 << 64, n, dtype=np.uint64)]
-    keys[1][rng.random(n) < 0.2] = -0.0
-    assert_bits(rel.hash_keys(keys, n), vkernels.hash_keys(keys, n))
-    # var-length keys are structurally numpy-only: the fused kernel
-    # refuses them, the dispatcher routes them
-    c = Column.from_strings(["a", "bb", ""])
-    with pytest.raises(TypeError):
-        rel.hash_keys([(c.offsets, c.values)], 3)
-
-
-def test_dispatch_hash_keys_var_length_routes_to_numpy(pallas_backend):
-    c = Column.from_strings(["a", "bb", "", "a"])
-    want = vkernels.hash_keys([(c.offsets, c.values)], 4)
-    assert_bits(kd.hash_keys([(c.offsets, c.values)], 4), want)
-
-
 # ---------------------------------------------------------------------------
-# gathers
+# gather
 # ---------------------------------------------------------------------------
 
 def test_filter_join_gather_triple_identical():
@@ -220,21 +200,6 @@ def test_filter_join_gather_triple_identical():
     all_miss = np.full(5, -1, np.int64)
     assert_bits(rel.filter_join_gather(empty, all_miss),
                 vkernels.filter_join_gather(empty, all_miss))
-
-
-@pytest.mark.parametrize("dtype", [np.int32, np.int64, np.float64])
-def test_gather_payload_matches_naive(dtype):
-    rng = np.random.default_rng(4)
-    src = rng.integers(-99, 99, 300).astype(dtype)
-    idx = rng.integers(-1, 300, 700).astype(np.int64)
-    got = rel.gather_payload(src, idx, 0)
-    naive = np.array([src[i] if i >= 0 else 0 for i in idx], dtype)
-    assert_bits(got, naive)
-    assert_bits(kd.gather_payload(src, idx, 0), naive)  # numpy arm
-    assert rel.gather_payload(src, np.empty(0, np.int64)).shape == (0,)
-    assert_bits(rel.gather_payload(np.empty(0, dtype),
-                                   np.full(3, -1, np.int64), 0),
-                np.zeros(3, dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -312,17 +277,6 @@ def test_hash_join_probe_searches_past_one_row_level():
         probe = rng.integers(0, card, npr, np.uint64)
         assert_bits(rel.hash_join_probe(build, probe),
                     vkernels.hash_join_probe(build, probe), f"{nb}x{npr}")
-
-
-def test_dispatch_hash_join_probe_serves_both_backends(monkeypatch):
-    rng = np.random.default_rng(12)
-    build, probe = _probe_case(rng, "duplicates")
-    want = vkernels.hash_join_probe(build, probe)
-    for backend in ("numpy", "pallas"):
-        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", backend)
-        kd.reset_device_calls()
-        assert_bits(kd.hash_join_probe(build, probe), want, backend)
-        assert sum(kd.device_calls().values()) == (backend == "pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -439,24 +393,81 @@ def test_grouped_reducers_many_groups_blocked():
 
 
 # ---------------------------------------------------------------------------
-# ineligible kernels stay on numpy; the registry documents why
+# the kernel table: dispatch under both backends, refusals stay on numpy
 # ---------------------------------------------------------------------------
 
+def _dispatch_args(name, dtype):
+    """Seeded arguments for one kernel of the table: ``-1`` sentinels,
+    duplicate probe runs, nulls; float reducer values with -0.0 and
+    NaN."""
+    rng = np.random.default_rng(sum(map(ord, name + dtype)))
+    n = 1500
+    if name == "hash_fixed":
+        return (rng.integers(-(1 << 62), 1 << 62, n, dtype=np.int64),)
+    if name == "combine_hashes":
+        return [rng.integers(0, 1 << 64, n, dtype=np.uint64)
+                for _ in range(3)], n
+    if name == "hash_join_probe":
+        return _probe_case(rng, "duplicates")
+    if name == "filter_join_gather":
+        sel = np.nonzero(rng.random(n) < 0.5)[0]
+        return sel, rng.integers(-1, len(sel), n).astype(np.int64)
+    order, starts = vkernels.group_ranges([rng.integers(0, 50, n)])
+    if dtype == "float64":
+        v = rng.standard_normal(n) * 10.0 ** rng.integers(-12, 12, n)
+        v[rng.random(n) < 0.1] = -0.0
+        v[rng.random(n) < 0.05] = np.nan
+    else:
+        v = rng.integers(-100, 100, n).astype(dtype)
+    return v, order, starts, rng.random(n) >= 0.2
+
+
+#: every kernel of the table on input it admits, and the reducers'
+#: float input, which the table refuses
+_DISPATCH_CASES = [(name, "int64") for name in kd.KERNELS] + [
+    (f"grouped_{how}", "float64") for how in ("sum", "min", "max")]
+
+
+@pytest.mark.parametrize("name,dtype", _DISPATCH_CASES,
+                         ids=[f"{n}:{d}" for n, d in _DISPATCH_CASES])
+def test_dispatch_serves_reference_bits_on_both_backends(monkeypatch, name,
+                                                         dtype):
+    """Under either backend a kernel returns its numpy reference's bits;
+    only the device backend serves it on the device, as one counted
+    call, and only for input its entry admits."""
+    args = _dispatch_args(name, dtype)
+    want = getattr(vkernels, name)(*args)
+    fn = kd.GROUPED_REDUCERS[name[len("grouped_"):]] \
+        if name.startswith("grouped_") else getattr(kd, name)
+    for backend in ("numpy", kd.DEVICE_BACKEND):
+        monkeypatch.setenv(kd.ENV, backend)
+        kd.reset_device_calls()
+        assert_bits(fn(*args), want, f"{backend} {name}[{dtype}]")
+        served = backend == kd.DEVICE_BACKEND and dtype != "float64"
+        assert kd.device_calls() == (
+            {(name, rel.platform()): 1} if served else {}), backend
+
+
 def test_float_reducers_are_registry_ineligible():
-    for key in ("grouped_sum:float", "grouped_min:float",
-                "grouped_max:float", "grouped_mean"):
-        e = kd.REGISTRY[key]
-        assert not e.eligible and e.reason
-    assert "order" in kd.REGISTRY["grouped_sum:float"].reason
-    assert not kd.eligible("grouped_sum", np.float64)
-    assert kd.eligible("grouped_sum", np.int64)
-    assert not kd.eligible("no_such_kernel")    # unknown => numpy
+    """The table's one admission rule: sum, min and max reduce integer
+    and bool values only; the mean is never a device kernel."""
+    order, starts = vkernels.group_ranges([np.zeros(4, np.int64)])
+    for name in ("grouped_sum", "grouped_min", "grouped_max"):
+        admits = kd.KERNELS[name].admits
+        for dt in (np.float64, np.float32):
+            assert not admits(np.zeros(4, dt), order, starts, None), name
+        for dt in (np.int32, np.int64, np.uint64, np.bool_):
+            assert admits(np.zeros(4, dt), order, starts, None), name
+    assert kd.KERNELS["grouped_count"].admits(np.zeros(4), order, starts)
+    assert kd.KERNELS["hash_fixed"].admits(np.zeros(4))
+    assert "grouped_mean" not in kd.KERNELS
+    assert kd.GROUPED_REDUCERS["mean"] is vkernels.grouped_mean
 
 
 def test_float_sum_dispatch_stays_numpy(pallas_backend):
-    """With the pallas backend active, a float grouped_sum must serve
+    """With the device backend active, a float grouped_sum must serve
     the numpy bits — the sequential np.bincount accumulation — and the
-    pallas port must refuse float input outright (it cannot honor the
+    device kernel must refuse float input outright (it cannot honor the
     order contract)."""
     rng = np.random.default_rng(5)
     n = 2000
@@ -479,11 +490,7 @@ def test_float_sum_dispatch_stays_numpy(pallas_backend):
 
 def test_self_check_admits_current_kernels(pallas_backend):
     res = kd.self_check()
-    eligible = [k for k, e in kd.REGISTRY.items() if e.eligible]
-    assert all(res[k] == "ok" for k in eligible), res
-    assert res["hash_fixed"] == "ok"
-    assert res["grouped_sum:int"] == "ok"
-    assert res["grouped_sum:float"].startswith("ineligible")
+    assert res == {name: "ok" for name in kd.KERNELS}, res
 
 
 def test_self_check_raises_on_bit_regressions(pallas_backend, monkeypatch):
@@ -500,16 +507,16 @@ def test_self_check_raises_on_bit_regressions(pallas_backend, monkeypatch):
     monkeypatch.setattr(rel, "grouped_sum", bad_grouped_sum)
     assert code_fingerprint(ops.group_by) != fp_before, \
         "kernel edit must change the cone"
-    with pytest.raises(RuntimeError, match="grouped_sum:int"):
+    with pytest.raises(RuntimeError, match="grouped_sum"):
         kd.self_check()
-    assert kd.eligible("grouped_sum", np.int64), \
-        "the registry is static: a failed check raises, it never demotes"
+    assert kd.KERNELS["grouped_sum"].admits(np.arange(3)), \
+        "the table is static: a failed check raises, it never demotes"
 
 
 def test_unavailable_pallas_raises_naming_the_knob(monkeypatch):
-    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
-    monkeypatch.setattr(kd, "_pallas_mod", None)
-    monkeypatch.setattr(kd, "_pallas_error",
+    monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
+    monkeypatch.setattr(kd, "_device_mod", None)
+    monkeypatch.setattr(kd, "_device_error",
                         ImportError("jax not installed"))
     v = np.arange(10, dtype=np.int64)
     with pytest.raises(RuntimeError,
@@ -537,7 +544,7 @@ def test_device_calls_counted_per_kernel_and_platform(pallas_backend):
     assert kd.device_calls() == {("hash_fixed", plat): 2,
                                  ("grouped_sum", plat): 1,
                                  ("hash_join_probe", plat): 1}, \
-        "the float sum is registry-ineligible and must not count"
+        "the table refuses the float sum, which must not count"
     kd.reset_device_calls()
     assert kd.device_calls() == {}
 
@@ -549,14 +556,14 @@ def test_process_executor_refuses_pallas_backend(tmp_path, monkeypatch):
     store = BufferStore(backing="file", data_dir=str(tmp_path / "store"))
     try:
         rm = ResourceManager(store, RMConfig())
-        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+        monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
         with pytest.raises(ValueError, match="ZERROW_KERNEL_BACKEND"):
             ProcessWorkerExecutor(store, rm, workers=1)
         # flipping the knob after construction is refused before any
         # worker is spawned
         monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "numpy")
         ex = ProcessWorkerExecutor(store, rm, workers=1)
-        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+        monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
         with pytest.raises(ValueError, match="thread executor"):
             ex._ensure_pool()
         assert ex._pool is None
@@ -584,7 +591,7 @@ def test_backend_flip_changes_only_relational_fingerprints(monkeypatch):
     monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "numpy")
     fp_np = [code_fingerprint(f) for f in relational_ops + unrelated]
     assert all(fp_np)
-    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+    monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
     fp_pl = [code_fingerprint(f) for f in relational_ops + unrelated]
     assert all(fp_pl)
     k = len(relational_ops)
@@ -595,7 +602,7 @@ def test_backend_flip_changes_only_relational_fingerprints(monkeypatch):
 
 
 def test_pallas_kernel_edit_invalidates_join_cone(monkeypatch):
-    monkeypatch.setenv("ZERROW_KERNEL_BACKEND", "pallas")
+    monkeypatch.setenv(kd.ENV, kd.DEVICE_BACKEND)
     fp1 = code_fingerprint(ops.join)
     fj1 = code_fingerprint(ops.filter_join)
     gb1 = code_fingerprint(ops.group_by)
@@ -686,7 +693,7 @@ def test_backend_flip_recomputes_exactly_join_groupby_cone(
     out2, (runs2, hits2, _) = run("numpy")         # warm, same backend
     assert out2 == out1
     assert (runs2, hits2) == (0, 5)
-    out3, (runs3, hits3, states) = run("pallas")   # flip backend
+    out3, (runs3, hits3, states) = run(kd.DEVICE_BACKEND)   # flip
     assert out3 == out1, "pallas cone diverged from the numpy bits"
     assert (runs3, hits3) == (2, 3), \
         f"backend flip should re-run exactly join+agg, got {states}"
@@ -720,8 +727,8 @@ def test_join_group_by_tables_bit_identical_across_backends(monkeypatch):
             "any": ("flag", "max")}
 
     outs, raw = {}, {}
-    for backend in ("numpy", "pallas"):
-        monkeypatch.setenv("ZERROW_KERNEL_BACKEND", backend)
+    for backend in ("numpy", kd.DEVICE_BACKEND):
+        monkeypatch.setenv(kd.ENV, backend)
         j = ops.join(left, right, on="cust", how="left")
         g = ops.group_by(j, "country", aggs)
         outs[backend] = g.to_pydict()
@@ -730,9 +737,9 @@ def test_join_group_by_tables_bit_identical_across_backends(monkeypatch):
             for f, c in zip(g.batches[0].schema.fields,
                             g.batches[0].columns)
             if c.type.is_primitive}
-    assert outs["numpy"] == outs["pallas"]
+    assert outs["numpy"] == outs[kd.DEVICE_BACKEND]
     for name, va in raw["numpy"].items():        # raw buffer bits too
-        vb = raw["pallas"][name]
+        vb = raw[kd.DEVICE_BACKEND][name]
         assert va.dtype == vb.dtype, name
         assert np.array_equal(va, vb, equal_nan=va.dtype.kind == "f"), \
             name

@@ -86,19 +86,16 @@ def _cases(s):
         "hash_fixed": (rel._hash_core, (rows,), {"sign": 0}),
         "hash_fixed:float": (rel._hash_core, (rows,), {"sign": 1 << 63}),
         "combine_hashes": (rel._combine_core, ((rows, rows),), {}),
-        "hash_keys": (rel._hash_keys_core, ((rows, rows),),
-                      {"signs": (0, 1 << 63)}),
         "filter_join_gather": (rel._gather_core, (src, idx, fill), {}),
-        "gather_payload": (rel._gather_core, (src, idx, fill), {}),
         **probe,
         **seg,
     }
 
 
-KERNELS = ("hash_fixed", "hash_fixed:float", "combine_hashes", "hash_keys",
+KERNELS = ("hash_fixed", "hash_fixed:float", "combine_hashes",
            "hash_join_probe:count", "hash_join_probe:expand",
-           "filter_join_gather", "gather_payload", "grouped_count",
-           "grouped_sum", "grouped_min", "grouped_max")
+           "filter_join_gather", "grouped_count", "grouped_sum",
+           "grouped_min", "grouped_max")
 
 
 @pytest.mark.parametrize("kernel", KERNELS)
@@ -156,9 +153,7 @@ def test_join_probe_lowers_without_loop(one_chip, stage):
 
 def test_cases_cover_every_registered_device_kernel():
     from repro.core import kdispatch
-    eligible = {k.split(":")[0] for k, e in kdispatch.REGISTRY.items()
-                if e.eligible}
-    assert eligible == {k.split(":")[0] for k in KERNELS}
+    assert set(kdispatch.KERNELS) == {k.split(":")[0] for k in KERNELS}
     # the real sizes are their own buckets: no padding at the smoke's size
     assert all(rel._bucket(n) == n
                for n in (ROWS, SOURCE_ROWS, GROUPS, BUILD_ROWS, PAIRS))
